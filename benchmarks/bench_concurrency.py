@@ -39,7 +39,6 @@ import time
 import numpy as np
 
 from repro.core import Session, SchedulerConfig
-from repro.core.builder import QueryBuilder, col
 from repro.tpch import dbgen, oracle, queries
 
 from .common import emit
@@ -206,33 +205,6 @@ def run(sf: float = 0.005) -> None:
 SMALL_PER_CLIENT = 3    # one query of each shape per client
 
 
-def _small_queries(catalog, order_keys, n: int):
-    """``n`` distinct-literal small queries cycling three compatible
-    shapes (point lookup / filtered global agg / low-card group-by), so
-    the batching scheduler forms one stacked launch group per shape."""
-    out = []
-    for i in range(n):
-        kind = i % 3
-        if kind == 0:
-            key = int(order_keys[(i * 37) % len(order_keys)])
-            out.append(QueryBuilder.scan(catalog, "orders")
-                       .filter(col("o_orderkey") == key)
-                       .project("o_orderkey", "o_totalprice"))
-        elif kind == 1:
-            out.append(QueryBuilder.scan(catalog, "lineitem")
-                       .filter(col("l_quantity") < float(2 + (i % 47)))
-                       .project(rev=col("l_extendedprice")
-                                * col("l_discount"))
-                       .agg(total=("sum", "rev"), n=("count", None)))
-        else:
-            out.append(QueryBuilder.scan(catalog, "lineitem")
-                       .filter(col("l_quantity") < float(3 + (i % 43)))
-                       .group_by("l_returnflag")
-                       .agg(total=("sum", "l_extendedprice"),
-                            n=("count", None)))
-    return out
-
-
 def _assert_checksums(ref: dict, got: dict, label: str) -> None:
     """Row-count + per-column checksum identity (floats to reduction
     order; ints/keys exact)."""
@@ -312,7 +284,7 @@ def run_small_queries(sf: float = 0.005, clients: int = 16) -> None:
     data = dbgen.generate(sf=sf)
     order_keys = np.asarray(data["orders"]["o_orderkey"])
     n_queries = clients * SMALL_PER_CLIENT
-    builders = _small_queries(catalog, order_keys, n_queries)
+    builders = queries.small_queries(catalog, order_keys, n_queries)
 
     # scheduler-less serial reference: timing baseline AND the oracle the
     # batched results must be row/checksum-identical to. Every mode runs

@@ -19,6 +19,9 @@ def main(argv=None) -> None:
                         help="substring filter on suite names")
     args = parser.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import (bench_adaptive, bench_barebones, bench_cold_hot,
                    bench_concurrency, bench_cost_perf, bench_exchange,
                    bench_kernels, bench_outofcore, bench_q5_scaling,

@@ -12,6 +12,7 @@ import time
 import jax
 
 from repro.core import HostExchange, ICIExchange, Session
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_engine_mesh
 from repro.tpch import dbgen, queries
 
@@ -42,4 +43,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

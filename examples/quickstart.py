@@ -9,6 +9,7 @@ import numpy as np
 
 from repro.core import ICIExchange, Session, dtypes as dt
 from repro.core.expr import col
+from repro.launch.compile_cache import enable_compile_cache
 from repro.tpch import dbgen, queries
 
 
@@ -54,4 +55,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -17,6 +17,7 @@ import threading
 import time
 
 from repro.core import Session, SchedulerConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.tpch import dbgen, queries
 
 # a "dashboard" of quick queries each client refreshes; repeats across
@@ -82,4 +83,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -78,6 +78,8 @@ class ParamRef(Expr):
     every future batch of the same shape) regardless of literal values.
     """
 
+    KERNEL_SAFE = True
+
     idx: int
     dtype: dt.DType
 
@@ -343,7 +345,8 @@ def apply_batched_stages(table: DeviceTable, stages: Sequence[fused.Stage],
                 cols[out_name] = v
                 schema[out_name] = e.out_dtype(cur.schema)
             cur = DeviceTable(cols, cur.validity, schema)
-    return cur, jnp.stack(masks)
+    # stacked as int32: inside the Pallas kernel Mosaic cannot stack i1
+    return cur, jnp.stack([m.astype(jnp.int32) for m in masks]) != 0
 
 
 @functools.lru_cache(maxsize=None)
@@ -360,11 +363,13 @@ def _compiled_morsel(program: BatchProgram, n_members: int, spec, backend):
         t = DeviceTable({n: a[0] for n, a in table.columns.items()},
                         table.validity[0], dict(table.schema))
         if backend == "pallas":
-            return fused.fused_batch_program(
-                t, params,
-                lambda tb, pr: apply_batched_stages(
-                    tb, program.pre_stages, pr, n_members),
-                n_members)
+            if fused.stages_in_kernel(program.pre_stages):
+                return fused.fused_batch_program(
+                    t, params,
+                    lambda tb, pr: apply_batched_stages(
+                        tb, program.pre_stages, pr, n_members),
+                    n_members)
+            kernel_ops.mark_fallback("fused_batch")
         return apply_batched_stages(t, program.pre_stages, params, n_members)
 
     used: set = set()
@@ -454,7 +459,8 @@ def _stacked_segment_agg(vals, member_sorted, gids, max_groups: int,
         # one-hot column, so union-invalid rows drop out exactly as they
         # did under the sentinel segment id. Integer inputs contract with
         # an integer accumulator (no float round-trip), so int sums and
-        # counts stay exact.
+        # counts stay exact; float inputs contract at HIGHEST precision
+        # (a TPU's default matmul rounds them to bfloat16).
         onehot = (gids[:, None]
                   == jnp.arange(max_groups, dtype=gids.dtype)[None, :])
         if kind == "count":
@@ -464,6 +470,7 @@ def _stacked_segment_agg(vals, member_sorted, gids, max_groups: int,
                             jnp.zeros((), vals.dtype))
         out = jax.lax.dot_general(
             acc, onehot.astype(acc.dtype), (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=acc.dtype)
         return out.reshape(n_members, max_groups)
 

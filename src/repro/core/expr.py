@@ -84,6 +84,19 @@ class Expr:
         """Set of column names this expression reads."""
         raise NotImplementedError
 
+    # True on node types whose evaluation lowers to elementwise vector ops
+    # inside a Mosaic-compiled Pallas kernel (no gathers, searches or
+    # array constants); see ``in_kernel``
+    KERNEL_SAFE = False
+
+
+def in_kernel(e: Expr) -> bool:
+    """Whether ``e`` can be evaluated inside a compiled Pallas kernel body:
+    every node of the tree has ``KERNEL_SAFE`` set."""
+    return e.KERNEL_SAFE and all(
+        in_kernel(v) for f in dataclasses.fields(e)
+        if isinstance(v := getattr(e, f.name), Expr))
+
 
 def _wrap(v) -> "Expr":
     return v if isinstance(v, Expr) else Literal(v)
@@ -92,6 +105,8 @@ def _wrap(v) -> "Expr":
 @dataclasses.dataclass(eq=False)
 class ColumnRef(Expr):
     """Reference to an input column by name (``col("l_quantity")``)."""
+
+    KERNEL_SAFE = True
 
     name: str
 
@@ -111,6 +126,8 @@ class ColumnRef(Expr):
 @dataclasses.dataclass(eq=False)
 class Literal(Expr):
     """Constant scalar; dtype inferred from the python value if absent."""
+
+    KERNEL_SAFE = True
 
     value: Any
     dtype: dt.DType = None  # inferred if None
@@ -150,6 +167,8 @@ _BOOLOP = {"and": jnp.logical_and, "or": jnp.logical_or}
 class BinaryOp(Expr):
     """Arithmetic/comparison/boolean operator over two subexpressions."""
 
+    KERNEL_SAFE = True
+
     op: str
     lhs: Expr
     rhs: Expr
@@ -188,6 +207,8 @@ class BinaryOp(Expr):
 class UnaryOp(Expr):
     """``not`` / ``neg`` over one subexpression."""
 
+    KERNEL_SAFE = True
+
     op: str
     operand: Expr
 
@@ -205,6 +226,8 @@ class UnaryOp(Expr):
 @dataclasses.dataclass(eq=False)
 class IsIn(Expr):
     """Membership against a small literal set (SQL ``IN``)."""
+
+    KERNEL_SAFE = True
 
     operand: Expr
     values: Tuple[Any, ...]

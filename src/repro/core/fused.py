@@ -28,8 +28,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..kernels import ops as kernel_ops
-from ..kernels.hash_probe import probe_loop
+from ..kernels.hash_probe import (PROBE_COMPILER_PARAMS, probe_loop,
+                                  table_planes)
 from . import relational as rel
+from .expr import in_kernel
 from .table import DeviceTable
 
 ROW_BLOCK = 1024
@@ -56,6 +58,15 @@ def apply_stages(table: DeviceTable, stages: Sequence[Stage]) -> DeviceTable:
                 schema[out_name] = e.out_dtype(table.schema)
             table = DeviceTable(cols, table.validity, schema)
     return table
+
+
+def stages_in_kernel(stages: Sequence[Stage]) -> bool:
+    """Whether every filter and projection of ``stages`` can run inside a
+    compiled kernel body (``expr.in_kernel``). LIKE patterns, EXTRACT and
+    the like cannot: their runs stay unfused on the XLA path."""
+    exprs = [f for f, _ in stages if f is not None]
+    exprs += [e for _, proj in stages if proj is not None for _, e in proj]
+    return all(in_kernel(e) for e in exprs)
 
 
 def probe_key(table: DeviceTable, key_names, pack, empty_key: int):
@@ -117,8 +128,8 @@ def fused_morsel_program(table: DeviceTable, stages: Sequence[Stage],
         col_refs, valid_ref = refs[:n_in], refs[n_in]
         pos = n_in + 1
         if probe is not None:
-            tk_ref, tv_ref = refs[pos], refs[pos + 1]
-            pos += 2
+            planes_ref = refs[pos]
+            pos += 1
         out_refs = refs[pos:]
         t = DeviceTable({n: r[...] for n, r in zip(names, col_refs)},
                         valid_ref[...], dict(in_schema))
@@ -130,7 +141,7 @@ def fused_morsel_program(table: DeviceTable, stages: Sequence[Stage],
             key = probe_key(t, probe["probe_keys"], probe["pack"],
                             probe["empty_key"])
             found, bidx = probe_loop(
-                tk_ref[...], tv_ref[...], key,
+                planes_ref, key,
                 table_size=probe["tk"].shape[0],
                 empty_key=probe["empty_key"],
                 max_probes=probe["max_probes"])
@@ -144,10 +155,9 @@ def fused_morsel_program(table: DeviceTable, stages: Sequence[Stage],
     in_specs = [_block_spec(a.shape, row_block) for a in in_arrays]
     operands = list(in_arrays)
     if probe is not None:
-        t_slots = probe["tk"].shape[0]
-        in_specs += [pl.BlockSpec((t_slots,), lambda i: (0,)),
-                     pl.BlockSpec((t_slots,), lambda i: (0,))]
-        operands += [probe["tk"], probe["tv"]]
+        planes = table_planes(probe["tk"], probe["tv"])
+        in_specs.append(pl.BlockSpec(planes.shape, lambda i: (0, 0)))
+        operands.append(planes)
 
     out_shapes, out_specs = [], []
     for n in out_names:
@@ -164,6 +174,7 @@ def fused_morsel_program(table: DeviceTable, stages: Sequence[Stage],
     outs = pl.pallas_call(
         kernel, grid=(n_pad // row_block,),
         in_specs=in_specs, out_specs=out_specs, out_shape=out_shapes,
+        compiler_params=None if probe is None else PROBE_COMPILER_PARAMS,
         interpret=interpret,
     )(*operands)
     outs = [o[:cap] for o in outs]
@@ -222,7 +233,8 @@ def fused_batch_program(table: DeviceTable, params: Tuple,
         for k, n in enumerate(out_names):
             out_refs[k][...] = block.columns[n]
         out_refs[len(out_names)][...] = block.validity
-        out_refs[len(out_names) + 1][...] = masks
+        # int32 lanes: Mosaic cannot relayout a stacked i1 mask
+        out_refs[len(out_names) + 1][...] = masks.astype(jnp.int32)
 
     in_specs = [_block_spec(a.shape, row_block) for a in in_arrays]
     # every parameter lane rides whole into each grid step
@@ -238,7 +250,7 @@ def fused_batch_program(table: DeviceTable, params: Tuple,
         out_specs.append(_block_spec(shape, row_block))
     out_shapes.append(jax.ShapeDtypeStruct((n_pad,), jnp.bool_))
     out_specs.append(pl.BlockSpec((row_block,), lambda i: (i,)))
-    out_shapes.append(jax.ShapeDtypeStruct((n_members, n_pad), jnp.bool_))
+    out_shapes.append(jax.ShapeDtypeStruct((n_members, n_pad), jnp.int32))
     out_specs.append(
         pl.BlockSpec((n_members, row_block), lambda i: (0, i)))
 
@@ -250,7 +262,7 @@ def fused_batch_program(table: DeviceTable, params: Tuple,
 
     cols = [o[:cap] for o in outs[:len(out_names)]]
     validity = outs[len(out_names)][:cap]
-    masks = outs[len(out_names) + 1][:, :cap]
+    masks = outs[len(out_names) + 1][:, :cap] != 0
     out_table = DeviceTable(dict(zip(out_names, cols)), validity,
                             out_schema)
     return out_table, masks

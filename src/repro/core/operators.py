@@ -1056,7 +1056,9 @@ def fuse_morsel_pipeline(pipe: Pipeline) -> None:
 
     A lone FilterProject stays unfused (same dispatch count either way);
     expansion probes, jnp-state joins and compacting stages keep their
-    standalone operators.
+    standalone operators. A run with an expression no compiled kernel can
+    evaluate (``fused.stages_in_kernel``) stays unfused too, counted once
+    as ``fallback_fused``.
     """
     if kernel_ops.current_backend() != "pallas":
         return
@@ -1066,8 +1068,14 @@ def fuse_morsel_pipeline(pipe: Pipeline) -> None:
     def stages():
         return [(fp.filter_expr, fp.projections) for fp in run]
 
+    def fusible():
+        if fused.stages_in_kernel(stages()):
+            return True
+        kernel_ops.count_dispatch("fallback_fused")
+        return False
+
     def flush():
-        if len(run) >= 2:
+        if len(run) >= 2 and fusible():
             new_ops.append(FusedMorsel(stages()))
         else:
             new_ops.extend(run)
@@ -1078,7 +1086,11 @@ def fuse_morsel_pipeline(pipe: Pipeline) -> None:
             run.append(op)
         elif (isinstance(op, HashJoin) and run
                 and op._hash_state is not None and not op._multi):
-            new_ops.append(FusedMorsel(stages(), join=op))
+            if fusible():
+                new_ops.append(FusedMorsel(stages(), join=op))
+            else:
+                new_ops.extend(run)
+                new_ops.append(op)
             run.clear()
         else:
             flush()

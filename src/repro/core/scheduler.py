@@ -317,6 +317,8 @@ class QueryScheduler:
         self.spill_admitted = 0
         self.batches = 0           # stacked launches (>= 2 members each)
         self.batched_queries = 0   # queries served via a stacked launch
+        self.batch_failures = 0    # stacked launches re-run solo after an error
+        self.first_batch_failure: Optional[str] = None
 
     # -- public API ---------------------------------------------------------
     def submit(self, plan: P.PlanNode, priority: int = 0,
@@ -515,8 +517,10 @@ class QueryScheduler:
         """Synchronous submit-and-wait (the serving path for one query)."""
         return self.submit(plan, priority).result()
 
-    def stats(self) -> Dict[str, int]:
-        """Served/rejected counters and cache hit/miss totals."""
+    def stats(self) -> Dict[str, object]:
+        """Served/rejected counters and cache hit/miss totals, plus the
+        number of stacked launches that failed and were re-run solo and
+        the first such failure's text (None while there is none)."""
         with self._cond:
             return {
                 "completed": self.completed,
@@ -526,6 +530,8 @@ class QueryScheduler:
                 "spill_admitted": self.spill_admitted,
                 "batches": self.batches,
                 "batched_queries": self.batched_queries,
+                "batch_failures": self.batch_failures,
+                "first_batch_failure": self.first_batch_failure,
                 "queued": len(self._pending),
                 "running": self._running,
                 "mem_in_use": self._mem_in_use,
@@ -734,7 +740,14 @@ class QueryScheduler:
                 self.completed += len(members)
                 self.batches += 1
                 self.batched_queries += len(members)
-        except BaseException:  # noqa: BLE001 -- solo fallback delivers
+        except Exception as exc:  # noqa: BLE001 -- solo fallback delivers
+            # counted and kept, so a stacked program that fails to compile
+            # or run shows up in stats() instead of hiding behind the
+            # (correct, slower) solo re-runs
+            with self._cond:
+                self.batch_failures += 1
+                if self.first_batch_failure is None:
+                    self.first_batch_failure = f"{type(exc).__name__}: {exc}"
             for m in members:
                 self._execute(m)
 

@@ -1,7 +1,7 @@
 """Segmented aggregation via one-hot MXU scatter-add (Pallas).
 
 cuDF's hash-aggregation scatter is warp-atomic on GPU; the TPU has no
-atomics, so the adaptation (DESIGN.md §2) turns scatter-add into a matmul:
+atomics, so the adaptation turns scatter-add into a matmul:
 for each row block, one_hot(gids)ᵀ @ values accumulates onto the group
 vector using the MXU — the systolic array does the reduction. The grid is
 sequential on TPU, so output-block accumulation across row blocks is safe.
@@ -66,7 +66,11 @@ def _kernel(gid_ref, val_ref, out_ref, *, group_block: int):
     local = gids - pl.program_id(0) * group_block  # [R], this group slab
     onehot = (jax.lax.broadcasted_iota(jnp.int32, (rows, group_block), 1)
               == local[:, None]).astype(jnp.float32)
-    contrib = onehot.T @ vals[:, None]             # [G_blk, 1] via MXU
+    # [G_blk, 1] via MXU; HIGHEST keeps the f32 values whole (the default
+    # TPU matmul rounds operands to bfloat16)
+    contrib = jax.lax.dot(onehot.T, vals[:, None],
+                          precision=jax.lax.Precision.HIGHEST,
+                          preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
@@ -107,23 +111,25 @@ def segmented_sum(gids, values, num_groups: int, row_block: int = ROW_BLOCK,
 
 
 def _int_kernel(gid_ref, val_ref, out_ref, *, group_block: int):
-    """Integer scatter-add: one-hot matmul with an int32 accumulator, so
-    sums stay exact past 2^24 (float32's integer range) and wrap at 2^31
-    exactly like the int32 ``jax.ops.segment_sum`` oracle."""
+    """Integer scatter-add: mask each row's value onto its group lane and
+    sum the row block on the VPU with an int32 accumulator, so sums stay
+    exact past 2^24 (float32's integer range) and wrap at 2^31 exactly
+    like the int32 ``jax.ops.segment_sum`` oracle. (The MXU has no int32
+    matmul on TPU v5e, so this kernel cannot share the float one-hot
+    matmul.)"""
     rows = gid_ref.shape[0]
     gids = gid_ref[...]
     vals = val_ref[...].astype(jnp.int32)
     local = gids - pl.program_id(0) * group_block
     onehot = (jax.lax.broadcasted_iota(jnp.int32, (rows, group_block), 1)
-              == local[:, None]).astype(jnp.int32)
-    contrib = jax.lax.dot(onehot.T, vals[:, None],
-                          preferred_element_type=jnp.int32)
+              == local[:, None])
+    contrib = jnp.sum(jnp.where(onehot, vals[:, None], 0), axis=0)
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[...] += contrib[:, 0]
+    out_ref[...] += contrib
 
 
 @functools.partial(jax.jit, static_argnames=("num_groups", "row_block",

@@ -86,9 +86,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         compiled = lowered.compile()
         compile_s = time.time() - t0
 
-    cost = compiled.cost_analysis()
-    # older jaxlibs return a one-element list of per-module dicts
-    cost = cost[0] if isinstance(cost, (list, tuple)) else (cost or {})
+    cost = compiled.cost_analysis() or {}
     flops = float(cost.get("flops", 0.0))
     bytes_accessed = float(cost.get("bytes accessed", 0.0))
     try:
@@ -116,7 +114,9 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     bytes_accessed = max(bytes_accessed, parsed["bytes"])
     coll = {k: int(v) for k, v in parsed["collectives"].items()}
     coll_total = int(parsed["collective_total"])
-    terms = rf.roofline_terms(flops, bytes_accessed, coll_total, chips=1)
+    # the dry-run models the production v5e pod
+    terms = rf.roofline_terms(flops, bytes_accessed, coll_total, chips=1,
+                              device_kind=rf.V5E)
     mf = rf.model_flops(cfg, shape)
 
     # per-device parameter residency (proves the FSDP+TP layout fits)

@@ -1,10 +1,11 @@
 """Roofline analysis from compiled dry-run artifacts (no hardware needed).
 
-Terms per (arch x shape x mesh), in seconds:
+Terms per (arch x shape x mesh), in seconds, from the peaks of the chip
+named by its ``device_kind`` (``PEAKS``):
 
-    compute    = HLO_FLOPs      / (chips * 197e12)      TPU v5e bf16 peak
-    memory     = HLO_bytes      / (chips * 819e9)       HBM bandwidth
-    collective = collective_B   / (chips * 50e9)        ICI per-link
+    compute    = HLO_FLOPs      / (chips * peak FLOP/s)
+    memory     = HLO_bytes      / (chips * HBM bytes/s)
+    collective = collective_B   / (chips * ICI bytes/s per link)
 
 cost_analysis() provides FLOPs/bytes; collective bytes are parsed from the
 post-SPMD HLO text (result-shape bytes of all-gather / all-reduce /
@@ -25,9 +26,24 @@ from __future__ import annotations
 import re
 from typing import Dict
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-ICI_BW = 50e9                # bytes/s/link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# "TPU v5 lite" is TPU v5e (Google Cloud documentation, "TPU v5e"):
+# 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of interchip interconnect
+# over four links = 50 GB/s per link.
+V5E = "TPU v5 lite"
+PEAKS: Dict[str, Dict[str, float]] = {
+    V5E: {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Peaks of one chip of ``device_kind``; a device missing from
+    ``PEAKS`` (a CPU among them) raises ``ValueError``."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3": 1,
                 "f8e5m2": 1, "s64": 8, "u64": 8, "s32": 4, "u32": 4,
@@ -74,11 +90,15 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
 
 
 def roofline_terms(flops: float, bytes_accessed: float,
-                   coll_bytes: int, chips: int) -> Dict[str, float]:
+                   coll_bytes: int, chips: int, *,
+                   device_kind: str) -> Dict[str, float]:
+    """Least seconds each resource needs on ``chips`` chips of
+    ``device_kind``."""
+    p = peaks(device_kind)
     return {
-        "compute_s": flops / (chips * PEAK_FLOPS),
-        "memory_s": bytes_accessed / (chips * HBM_BW),
-        "collective_s": coll_bytes / (chips * ICI_BW),
+        "compute_s": flops / (chips * p["flops"]),
+        "memory_s": bytes_accessed / (chips * p["hbm_bw"]),
+        "collective_s": coll_bytes / (chips * p["ici_bw"]),
     }
 
 
@@ -92,26 +112,28 @@ def measure_program(fn, *args, warmup: int = 1, iters: int = 3,
     """Roofline-vs-measured report for one jittable program at one shape.
 
     Lowers and compiles ``fn(*args)``, takes FLOPs / bytes-accessed from
-    the compiled cost analysis (a one-element list on some jax versions)
-    and collective bytes from the post-SPMD HLO text, and compares the
-    roofline time bound — the max of the ``roofline_terms`` — to the
-    measured per-call wall time. ``achieved_fraction`` is bound/measured:
-    ~1.0 means the program runs at the hardware ceiling for its dominant
-    term; off-TPU (interpret-mode kernels) the fraction is tiny and only
-    the relative ordering across kernels is meaningful.
+    the compiled cost analysis and collective bytes from the post-SPMD HLO
+    text, and compares the roofline time bound — the max of the
+    ``roofline_terms`` for the default device's kind — to the measured
+    per-call wall time. ``achieved_fraction`` is bound/measured: ~1.0
+    means the program runs at the hardware ceiling for its dominant term.
+    A device without published peaks (``PEAKS``), such as a CPU, raises
+    ``ValueError`` before anything runs.
     """
     import time
 
     import jax
 
+    kind = jax.devices()[0].device_kind
+    peaks(kind)
     jfn = jax.jit(fn)
     compiled = jfn.lower(*args).compile()
-    cost = compiled.cost_analysis()
-    cost = cost[0] if isinstance(cost, (list, tuple)) else (cost or {})
+    cost = compiled.cost_analysis() or {}
     flops = float(cost.get("flops", 0.0))
     bytes_accessed = float(cost.get("bytes accessed", 0.0))
     coll = sum(collective_bytes(compiled.as_text()).values())
-    terms = roofline_terms(flops, bytes_accessed, coll, chips=chips)
+    terms = roofline_terms(flops, bytes_accessed, coll, chips=chips,
+                           device_kind=kind)
     bound_s = max(terms.values())
     for _ in range(warmup):
         jax.block_until_ready(jfn(*args))

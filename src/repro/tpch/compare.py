@@ -1,35 +1,17 @@
-"""Shared helpers for TPC-H engine-vs-oracle comparison."""
+"""Engine-vs-oracle result comparison for TPC-H queries: the one
+comparator the tests, ``chip_smoke.py`` and the benchmarks share."""
 
 from __future__ import annotations
 
 import numpy as np
 
 
-def canon(result: dict, columns) -> list:
-    """Canonical multiset of rows over ``columns`` (order-insensitive,
-    float-rounded so float32 engine results compare to float64 oracle)."""
-    n = len(next(iter(result.values())))
-    rows = []
-    for i in range(n):
-        row = []
-        for c in columns:
-            v = result[c][i] if hasattr(result[c], "__getitem__") else result[c]
-            v = np.asarray(v)
-            if v.ndim >= 1 and v.dtype == np.uint8:     # bytes column
-                row.append(v.tobytes())
-            elif v.dtype.kind == "f":
-                x = float(v)
-                row.append(round(x / max(abs(x), 1.0), 4))  # relative rounding
-            elif v.dtype.kind == "S" or isinstance(result[c][i], bytes):
-                row.append(bytes(result[c][i]))
-            else:
-                row.append(int(v))
-        rows.append(tuple(row))
-    return sorted(rows)
-
-
 def assert_results_match(engine: dict, oracle: dict, qnum: int,
                          float_cols_rtol: float = 2e-3):
+    """Raise ``AssertionError`` unless ``engine`` holds the same rows as
+    ``oracle`` (order-insensitive) on their common columns: integer,
+    bytes and string columns exactly, float columns to ``float_cols_rtol``.
+    """
     common = [c for c in oracle.keys() if c in engine]
     assert common, f"q{qnum}: no common columns {list(engine)} vs {list(oracle)}"
     n_e = len(next(iter(engine.values())))

@@ -226,7 +226,12 @@ def generate(sf: float = 0.01, seed: int = 19940729) -> Dict[str, Dict[str, np.n
 
 def load_catalog(sf: float = 0.01, seed: int = 19940729) -> Catalog:
     """In-memory catalog (tests); for the storage path use write_dataset."""
-    data = generate(sf, seed)
+    return catalog_from(generate(sf, seed))
+
+
+def catalog_from(data: Dict[str, Dict[str, np.ndarray]]) -> Catalog:
+    """In-memory catalog over already generated ``data``, for callers that
+    also hand ``data`` to the oracle (generating it once)."""
     cat = Catalog()
     for name, tab in data.items():
         cat.register(InMemoryTable(name, tab, S.SCHEMAS[name],

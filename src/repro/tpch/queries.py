@@ -21,6 +21,7 @@ import dataclasses
 from typing import Callable, Dict
 
 from ..core import plan as P
+from ..core.builder import QueryBuilder
 from ..core.builder import table as _t
 from ..core.expr import col, date_lit, lit, prefix_code, year
 from ..core.optimizer import DEFAULT_CONFIG, optimize
@@ -670,3 +671,32 @@ def build_query(qnum: int, catalog, optimized: bool = True,
         return plan
     cfg = dataclasses.replace(DEFAULT_CONFIG, num_workers=num_workers)
     return optimize(plan, catalog, config=cfg)
+
+
+def small_queries(catalog, order_keys, n: int) -> list:
+    """``n`` distinct-literal small queries cycling three batch-eligible
+    shapes (point lookup on ``orders`` / filtered global aggregate / low-
+    cardinality group-by on ``lineitem``), so a batching scheduler forms
+    one stacked launch group per shape. ``order_keys`` supplies the point
+    lookups' keys. Returns unoptimized ``QueryBuilder`` objects."""
+    out = []
+    for i in range(n):
+        kind = i % 3
+        if kind == 0:
+            key = int(order_keys[(i * 37) % len(order_keys)])
+            out.append(QueryBuilder.scan(catalog, "orders")
+                       .filter(col("o_orderkey") == key)
+                       .project("o_orderkey", "o_totalprice"))
+        elif kind == 1:
+            out.append(QueryBuilder.scan(catalog, "lineitem")
+                       .filter(col("l_quantity") < float(2 + (i % 47)))
+                       .project(rev=col("l_extendedprice")
+                                * col("l_discount"))
+                       .agg(total=("sum", "rev"), n=("count", None)))
+        else:
+            out.append(QueryBuilder.scan(catalog, "lineitem")
+                       .filter(col("l_quantity") < float(3 + (i % 43)))
+                       .group_by("l_returnflag")
+                       .agg(total=("sum", "l_extendedprice"),
+                            n=("count", None)))
+    return out

@@ -2,7 +2,7 @@
 
 Twenty of the 22 spec queries expressed in the SQL subset the frontend
 lowers (see ``core/sql.py``); output column names match ``oracle.py`` so
-``tests.tpch_util.assert_results_match`` validates SQL-path executions the
+``repro.tpch.compare.assert_results_match`` validates SQL-path executions the
 same way it validates the hand-built plans. Two queries need constructs
 the engine has no operator for and are intentionally absent, documented in
 ``UNSUPPORTED``: Q13 (LEFT OUTER JOIN aggregation) and Q21 (correlated

@@ -260,6 +260,40 @@ def test_disabled_batching_is_inert():
         session.scheduler().close()
 
 
+def test_stacked_failure_is_counted_and_solo_results_stay_correct(
+        monkeypatch):
+    """A stacked launch that raises re-runs its members solo: the results
+    stay right, and ``stats()`` counts the failure and keeps its text."""
+    from repro.core.driver import Driver
+
+    def broken(self, shapes, lanes=None):
+        raise RuntimeError("stacked program refused")
+
+    monkeypatch.setattr(Driver, "collect_batch", broken)
+    data, catalog = dataset()
+    keys = np.asarray(data["orders"]["o_orderkey"])
+    builders = [_workload(catalog, keys, 3)[1]] * 4 + \
+        _workload(catalog, keys, 6)[3:]
+    serial = Session(catalog, num_workers=1, batch_rows=16384)
+    refs = [serial.execute(b.optimized()) for b in builders]
+
+    session = Session(catalog, num_workers=1, batch_rows=16384)
+    session.scheduler_config = _sched_config()
+    try:
+        assert session.scheduler().stats()["batch_failures"] == 0
+        handles = _submit_concurrently(session, builders)
+        stats = session.scheduler().stats()
+        assert stats["batches"] == 0
+        assert stats["batch_failures"] >= 1
+        assert stats["first_batch_failure"] == (
+            "RuntimeError: stacked program refused")
+        for i, h in enumerate(handles):
+            assert "batch" not in h.executor_stats
+            _assert_columns_equal(refs[i], h.result(), f"q{i}")
+    finally:
+        session.scheduler().close()
+
+
 # ---------------------------------------------------------------------------
 # -m batching: batched == serial property test
 # ---------------------------------------------------------------------------

@@ -33,7 +33,8 @@ def test_query_on_8_device_mesh_matches_oracle():
 import jax
 import numpy as np
 assert jax.device_count() == 8, jax.devices()
-mesh = jax.make_mesh((8,), ("workers",))
+from repro.launch.mesh import make_engine_mesh
+mesh = make_engine_mesh(8)
 from repro.core import Session, ICIExchange
 from repro.tpch import dbgen, queries, oracle
 data = dbgen.generate(sf=0.002)
@@ -54,7 +55,8 @@ def test_ici_exchange_lowers_to_all_to_all():
 import jax
 import jax.numpy as jnp
 import numpy as np
-mesh = jax.make_mesh((8,), ("workers",))
+from repro.launch.mesh import make_engine_mesh
+mesh = make_engine_mesh(8)
 from repro.core import dtypes as dt
 from repro.core.table import DeviceTable
 from repro.core.exchange import ICIExchange, _partition_layout_table
@@ -81,7 +83,8 @@ def test_exchange_correctness_on_mesh():
 import jax
 import jax.numpy as jnp
 import numpy as np
-mesh = jax.make_mesh((8,), ("workers",))
+from repro.launch.mesh import make_engine_mesh
+mesh = make_engine_mesh(8)
 from repro.core import dtypes as dt
 from repro.core.table import DeviceTable
 from repro.core.exchange import ICIExchange, HostExchange

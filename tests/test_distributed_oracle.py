@@ -35,7 +35,7 @@ from repro.core import plan as P
 from repro.tpch import dbgen, oracle, queries
 
 from _hypothesis_compat import bools, sampled, seeded_given
-from tpch_util import assert_results_match
+from repro.tpch.compare import assert_results_match
 
 SF = float(os.environ.get("DIST_ORACLE_SF", "0.002"))
 WORKER_COUNTS = tuple(

@@ -39,7 +39,7 @@ from repro.core.scheduler import SchedulerConfig
 from repro.tpch import dbgen, oracle, queries
 
 from _hypothesis_compat import ints, seeded_given
-from tpch_util import assert_results_match
+from repro.tpch.compare import assert_results_match
 
 SF = float(os.environ.get("ADAPTIVE_SF", "0.002"))
 FALLBACK_SF = float(os.environ.get("ADAPTIVE_FALLBACK_SF", "0.02"))

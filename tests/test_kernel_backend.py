@@ -29,7 +29,7 @@ from repro.kernels import ops as kernel_ops
 from repro.tpch import dbgen, oracle, queries
 
 from _hypothesis_compat import bools, sampled, seeded_given
-from tpch_util import assert_results_match
+from repro.tpch.compare import assert_results_match
 
 SF = float(os.environ.get("KERNEL_BACKEND_SF", "0.002"))
 
@@ -137,6 +137,55 @@ def test_fused_pipeline_never_dispatches_under_jnp():
     _, catalog = dataset(SF)
     _, stats = run_backend(catalog, 3, 1, "jnp")
     assert stats["kernel_dispatch"] == {}
+
+
+def test_unkernelable_stages_stay_unfused():
+    """A filter->project run holding an expression no compiled kernel can
+    evaluate (a LIKE pattern) keeps its standalone XLA operators, counted
+    once as ``fallback_fused``; a kernel-safe run still fuses, and both
+    give the same rows."""
+    import numpy as np
+
+    from repro.core import dtypes as dt
+    from repro.core import operators as ops
+    from repro.core.expr import col, in_kernel
+    from repro.core.table import DeviceTable
+
+    like = col("p_type").startswith("PROMO")
+    assert not in_kernel(like)
+    assert in_kernel((col("p_size") > 5) & col("p_size").isin((1, 7)))
+    rng = np.random.default_rng(0)
+    types = ["PROMO BRUSHED", "LARGE PLATED", "PROMO ANODIZED"]
+    table = DeviceTable.from_numpy(
+        {"p_size": rng.integers(1, 50, 300).astype(np.int32),
+         "p_type": dt.encode_bytes(rng.choice(types, 300), 25)},
+        {"p_size": dt.INT32, "p_type": dt.bytes_(25)}, capacity=512)
+
+    def run(first_filter):
+        pipe = ops.Pipeline([
+            ops.FilterProject(first_filter),
+            ops.FilterProject(col("p_size") > 5,
+                              [("s2", col("p_size") * 2)])])
+        counts: dict = {}
+        with kernel_ops.use_pallas(), kernel_ops.collect_dispatches(counts):
+            ops.fuse_morsel_pipeline(pipe)
+            pipe.open()
+            out = pipe.add_input(table)[0]
+        kinds = [type(op).__name__ for op in pipe.ops]
+        return np.sort(np.asarray(out.columns["s2"])[
+            np.asarray(out.validity)]), kinds, counts
+
+    got, kinds, counts = run(like)
+    assert kinds == ["FilterProject", "FilterProject"]
+    assert counts.get("fallback_fused") == 1, counts
+    sizes = np.asarray(table.columns["p_size"])[:300]
+    promo = np.char.startswith(
+        np.array([bytes(r).decode() for r in np.asarray(
+            table.columns["p_type"])[:300]]), "PROMO")
+    np.testing.assert_array_equal(got, np.sort(2 * sizes[promo & (sizes > 5)]))
+
+    _, kinds, counts = run(col("p_size") < 40)
+    assert kinds == ["FusedMorsel"] and "fallback_fused" not in counts
 
 
 def test_compact_dispatches_on_scalar_subquery():

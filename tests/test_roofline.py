@@ -3,6 +3,7 @@ detection, term arithmetic."""
 
 import jax
 import jax.numpy as jnp
+import pytest
 
 from repro.launch import hloparse, roofline as rf
 
@@ -36,8 +37,6 @@ def test_scan_body_weighted_by_trip_count():
     # and confirm cost_analysis alone UNDER-counts the scan (the bug the
     # parser exists to fix)
     ca = jax.jit(scanned).lower(x, ws).compile().cost_analysis()
-    # older jaxlibs return a one-element list of per-module dicts
-    ca = ca[0] if isinstance(ca, (list, tuple)) else ca
     assert ca["flops"] < analytic / 2
 
 
@@ -92,12 +91,15 @@ print("COLL_OK", total)
 
 def test_roofline_terms_math():
     t = rf.roofline_terms(flops=197e12 * 256, bytes_accessed=819e9 * 256,
-                          coll_bytes=50e9 * 256, chips=256)
+                          coll_bytes=50e9 * 256, chips=256,
+                          device_kind="TPU v5 lite")
     assert abs(t["compute_s"] - 1.0) < 1e-9
     assert abs(t["memory_s"] - 1.0) < 1e-9
     assert abs(t["collective_s"] - 1.0) < 1e-9
     assert rf.dominant({"compute_s": 3, "memory_s": 2, "collective_s": 1}) \
         == "compute_s"
+    with pytest.raises(ValueError, match="no published peaks"):
+        rf.roofline_terms(1.0, 1.0, 0, chips=1, device_kind="cpu")
 
 
 def test_model_flops_definitions():

@@ -14,7 +14,7 @@ from repro.core.session import InMemoryTable
 from repro.tpch import dbgen, oracle, queries
 from repro.tpch import schema as S
 
-from tpch_util import assert_results_match
+from repro.tpch.compare import assert_results_match
 
 SF = 0.002
 

@@ -27,7 +27,7 @@ from repro.core.table import DeviceTable
 from repro.tpch import dbgen, oracle, queries
 
 from _hypothesis_compat import ints, sampled, seeded_given
-from tpch_util import assert_results_match
+from repro.tpch.compare import assert_results_match
 
 SF = 0.002
 

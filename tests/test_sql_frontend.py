@@ -26,7 +26,7 @@ from repro.core.builder import table as _t
 from repro.core.expr import col, date_lit, lit
 from repro.tpch import dbgen, oracle, sqltext
 
-from tpch_util import assert_results_match
+from repro.tpch.compare import assert_results_match
 
 SF = 0.002
 

@@ -30,7 +30,7 @@ from repro.tpch import dbgen, oracle, sqltext
 from sql_oracle import (HAVE_DUCKDB, SqlMismatch, check_sql,
                         connect_with_catalog, fuzz_queries, require_duckdb,
                         run_duckdb)
-from tpch_util import assert_results_match
+from repro.tpch.compare import assert_results_match
 
 pytestmark = pytest.mark.sql_oracle
 

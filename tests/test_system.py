@@ -91,3 +91,20 @@ def test_engine_feeds_training_data():
         losses.append(float(m["loss"]))
     assert np.isfinite(losses).all()
     assert losses[-1] < losses[0]
+
+
+def test_chip_smoke_refuses_without_tpu(tmp_path):
+    """``chip_smoke.py`` reports a chip result only from a TPU: on the CPU
+    it exits non-zero before generating data, and prints no ok line."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, os.path.join(repo, "chip_smoke.py")],
+                          env=env, cwd=tmp_path, capture_output=True,
+                          text=True, timeout=300)
+    assert proc.returncode != 0
+    assert "no TPU" in proc.stderr
+    assert '"ok"' not in proc.stdout and "data:" not in proc.stdout

@@ -11,7 +11,7 @@ import pytest
 from repro.core import HostExchange, ICIExchange, Session
 from repro.tpch import dbgen, oracle, queries
 
-from tpch_util import assert_results_match
+from repro.tpch.compare import assert_results_match
 
 SF = 0.005
 
