@@ -37,7 +37,6 @@ import dataclasses
 import functools
 import inspect
 import threading
-import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -49,9 +48,10 @@ from . import dtypes as dt
 from . import fused
 from . import plan as P
 from . import relational as rel
+from . import tracing
 from .expr import (BinaryOp, BytesMatch, ColumnRef, Expr, IsIn, Literal,
                    PrefixCode, UnaryOp, Year)
-from .operators import _OP_CACHES, _table_spec, lower_aggs
+from .operators import _OP_CACHES, _table_spec, lower_aggs, timed
 from .streaming import ScanStats
 from .table import DeviceTable, concat_tables
 
@@ -357,7 +357,7 @@ def _compiled_morsel(program: BatchProgram, n_members: int, spec, backend):
     accounting."""
     del spec
 
-    def body(table, params):
+    def stacked_morsel(table, params):
         # morsels arrive worker-stacked [1, cap]: drop the worker axis
         # (batching is W=1 only; the scheduler enforces it at extraction)
         t = DeviceTable({n: a[0] for n, a in table.columns.items()},
@@ -373,7 +373,7 @@ def _compiled_morsel(program: BatchProgram, n_members: int, spec, backend):
         return apply_batched_stages(t, program.pre_stages, params, n_members)
 
     used: set = set()
-    return jax.jit(body), used
+    return jax.jit(tracing.program("batch", stacked_morsel)), used
 
 
 _OP_CACHES.append(_compiled_morsel)
@@ -563,11 +563,11 @@ def _stacked_aggregate(table: DeviceTable, masks, program: BatchProgram,
 def _compiled_agg(program: BatchProgram, n_members: int, spec, backend):
     del spec, backend   # one entry (and used-set) per specialization
 
-    def body(table, masks):
+    def stacked_agg(table, masks):
         return _stacked_aggregate(table, masks, program, n_members)
 
     used: set = set()
-    return jax.jit(body), used
+    return jax.jit(tracing.program("batch", stacked_agg)), used
 
 
 _OP_CACHES.append(_compiled_agg)
@@ -577,11 +577,11 @@ _OP_CACHES.append(_compiled_agg)
 def _compiled_post(program: BatchProgram, spec, backend):
     del spec, backend
 
-    def body(table):
+    def post_stages(table):
         return fused.apply_stages(table, program.post_stages)
 
     used: set = set()
-    return jax.jit(body), used
+    return jax.jit(tracing.program("batch", post_stages)), used
 
 
 _OP_CACHES.append(_compiled_post)
@@ -615,6 +615,11 @@ def _agg_schema(program: BatchProgram, in_schema) -> Dict[str, dt.DType]:
 # ---------------------------------------------------------------------------
 # batched execution loop (called from Driver.collect_batch)
 # ---------------------------------------------------------------------------
+
+_NAME = "BatchedPipeline"
+_SPAN_ADD = f"repro.op.{_NAME}.add_input"
+_SPAN_FINISH = f"repro.op.{_NAME}.finish"
+
 
 def padded_members(n: int) -> int:
     """Member-lane count rounded up to a power of two: dummy lanes reuse
@@ -667,43 +672,21 @@ def run_batch(driver, shapes: Sequence[BatchShape],
         morsels = src.scan(1, columns, ctx.batch_rows, filter_expr=None,
                            **kwargs)
 
-    spent = 0.0
+    # one name for the stacked stage program's calls in op_host_seconds,
+    # spanned like an operator's (repro.op.BatchedPipeline.*)
+    secs = driver.op_host_seconds
     if program.has_agg:
         tables: List[DeviceTable] = []
         mask_parts: List[jax.Array] = []
         for morsel in morsels:
-            t0 = time.perf_counter()
-            out_table, masks = batch_morsel_op(program, lanes, morsel, params)
-            spent += time.perf_counter() - t0
+            out_table, masks = timed(_SPAN_ADD, _NAME, secs, batch_morsel_op,
+                                     program, lanes, morsel, params)
             tables.append(out_table)
             mask_parts.append(masks)
-        t0 = time.perf_counter()
-        # small-query contract: the projected scan output materializes on
-        # device (like any blocking aggregation input) and aggregates once
-        table = concat_tables(tables)
-        masks = (mask_parts[0] if len(mask_parts) == 1
-                 else jnp.concatenate(mask_parts, axis=1))
-        key_vals, agg_cols, emit = _record_replay(
-            _compiled_agg(program, lanes, _table_spec((table, masks)),
-                          kernel_ops.current_backend()),
-            table, masks)
-        schema = _agg_schema(program, table.schema)
-        results: List[Dict[str, np.ndarray]] = []
-        for b in range(n):
-            cols = {k: key_vals[k] for k in program.group_keys}
-            for out, _kind, _col in program.user_specs:
-                cols[out] = agg_cols[out][b]
-            member = DeviceTable(cols, emit[b], dict(schema))
-            if program.post_stages:
-                member = _record_replay(
-                    _compiled_post(program, _table_spec((member,)),
-                                   kernel_ops.current_backend()),
-                    member)
-            results.append(member.to_numpy())
-        spent += time.perf_counter() - t0
-        driver.op_seconds["BatchedPipeline"] = (
-            driver.op_seconds.get("BatchedPipeline", 0.0) + spent)
-        return results
+        members = timed(_SPAN_FINISH, _NAME, secs, _finish_agg, program,
+                        lanes, n, tables, mask_parts)
+        with tracing.span("repro.driver.to_host"):
+            return [m.to_numpy() for m in members]
 
     # row queries: per-morsel host scatter in morsel order — identical row
     # order to the solo path's flat[validity] collection
@@ -711,18 +694,47 @@ def run_batch(driver, shapes: Sequence[BatchShape],
         {} for _ in range(n)]
     out_names: List[str] = []
     for morsel in morsels:
-        t0 = time.perf_counter()
-        out_table, masks = batch_morsel_op(program, lanes, morsel, params)
-        spent += time.perf_counter() - t0
+        out_table, masks = timed(_SPAN_ADD, _NAME, secs, batch_morsel_op,
+                                 program, lanes, morsel, params)
         out_names = list(out_table.column_names)
-        masks_np = np.asarray(masks)
-        cols_np = {c: np.asarray(out_table.columns[c]) for c in out_names}
+        with tracing.span("repro.driver.to_host"):
+            masks_np = np.asarray(masks)
+            cols_np = {c: np.asarray(out_table.columns[c])
+                       for c in out_names}
         for b in range(n):
             sel = masks_np[b]
             for c in out_names:
                 acc[b].setdefault(c, []).append(cols_np[c][sel])
-    driver.op_seconds["BatchedPipeline"] = (
-        driver.op_seconds.get("BatchedPipeline", 0.0) + spent)
     return [
         {c: np.concatenate(parts[c]) for c in out_names}
         for parts in acc]
+
+
+def _finish_agg(program: BatchProgram, lanes: int, n: int,
+                tables: List[DeviceTable],
+                mask_parts: List[jax.Array]) -> List[DeviceTable]:
+    """The stacked aggregation over every morsel's output, split into one
+    device table per member (post stages applied)."""
+    # small-query contract: the projected scan output materializes on
+    # device (like any blocking aggregation input) and aggregates once
+    table = concat_tables(tables)
+    masks = (mask_parts[0] if len(mask_parts) == 1
+             else jnp.concatenate(mask_parts, axis=1))
+    key_vals, agg_cols, emit = _record_replay(
+        _compiled_agg(program, lanes, _table_spec((table, masks)),
+                      kernel_ops.current_backend()),
+        table, masks)
+    schema = _agg_schema(program, table.schema)
+    members: List[DeviceTable] = []
+    for b in range(n):
+        cols = {k: key_vals[k] for k in program.group_keys}
+        for out, _kind, _col in program.user_specs:
+            cols[out] = agg_cols[out][b]
+        member = DeviceTable(cols, emit[b], dict(schema))
+        if program.post_stages:
+            member = _record_replay(
+                _compiled_post(program, _table_spec((member,)),
+                               kernel_ops.current_backend()),
+                member)
+        members.append(member)
+    return members

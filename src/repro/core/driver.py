@@ -19,7 +19,6 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import inspect
-import time
 from typing import Dict, Iterator, Optional, Sequence
 
 import numpy as np
@@ -27,6 +26,7 @@ import numpy as np
 from ..kernels import ops as kernel_ops
 from . import operators as ops
 from . import plan as P
+from . import tracing
 from .exchange import ExchangeProtocol, ICIExchange
 from .streaming import ScanStats
 from .table import DeviceTable, concat_tables
@@ -118,12 +118,12 @@ class StreamingScan:
     """
 
     def __init__(self, table: str, morsels: Iterator[DeviceTable],
-                 stats: ScanStats, op_seconds: Optional[Dict[str, float]] = None):
+                 stats: ScanStats,
+                 op_host_seconds: Optional[Dict[str, float]] = None):
         self.table = table
         self.morsels = morsels
         self.stats = stats
-        self.pipe = ops.Pipeline()
-        self._op_seconds = op_seconds if op_seconds is not None else {}
+        self.pipe = ops.Pipeline(seconds=op_host_seconds)
 
     def fuse(self, op: ops.Operator) -> None:
         """Append an operator to the per-morsel scan pipeline (must be
@@ -131,24 +131,17 @@ class StreamingScan:
         self.pipe.ops.append(op)
 
     def batches(self) -> Iterator[DeviceTable]:
-        """Drain the prefetch queue through the fused per-morsel pipeline."""
-        spent = 0.0
+        """Drain the prefetch queue through the fused per-morsel pipeline
+        (each fused operator's calls are spanned and timed by the
+        ``Pipeline``)."""
         # collapse filter->project->probe runs into single-dispatch fused
         # kernels; runs here (not in fuse()) so every fused stage -- and
         # the query's backend scope -- is in place before the first morsel
         ops.fuse_morsel_pipeline(self.pipe)
         self.pipe.open()
         for morsel in self.morsels:
-            t0 = time.perf_counter()
-            outs = self.pipe.add_input(morsel)
-            spent += time.perf_counter() - t0
-            yield from outs
-        t0 = time.perf_counter()
-        outs = self.pipe.finish()
-        spent += time.perf_counter() - t0
-        self._op_seconds["StreamingScan"] = (
-            self._op_seconds.get("StreamingScan", 0.0) + spent)
-        yield from outs
+            yield from self.pipe.add_input(morsel)
+        yield from self.pipe.finish()
 
 
 def empty_executor_stats() -> Dict[str, object]:
@@ -161,7 +154,7 @@ def empty_executor_stats() -> Dict[str, object]:
     """
     return {
         "tables": {},
-        "op_seconds": {},
+        "op_host_seconds": {},
         "conversions": {},
         "exchange_protocol": "",
         "exchanges": {},
@@ -185,7 +178,9 @@ class Driver:
 
     def __init__(self, ctx: ExecutionContext):
         self.ctx = ctx
-        self.op_seconds: Dict[str, float] = {}
+        # per operator name, host seconds of the operator's own calls (the
+        # intervals of its repro.op.* spans): dispatch time, not device time
+        self.op_host_seconds: Dict[str, float] = {}
         self.conversion_stats: Dict[str, int] = {}
         self.scan_stats: Dict[str, ScanStats] = {}
         # per-query kernel dispatch counts (kind -> executions of a pallas
@@ -205,14 +200,14 @@ class Driver:
         self._feedback_matches: Dict[int, int] = {}
 
     def executor_stats(self) -> Dict[str, object]:
-        """Per-query executor stats: scan counters, operator timings,
+        """Per-query executor stats: scan counters, operator host times,
         kernel backend + dispatch counts, per-fragment exchange counters
         (rows/bytes moved, host staging), per-tier spill counters, and the
         feedback-store summary. Same key shape as
         ``empty_executor_stats()``."""
         return {
             "tables": {t: s.summary() for t, s in self.scan_stats.items()},
-            "op_seconds": dict(self.op_seconds),
+            "op_host_seconds": dict(self.op_host_seconds),
             "conversions": dict(self.conversion_stats),
             "exchange_protocol": self.ctx.exchange.name,
             "exchanges": {k: dict(v) for k, v in self.exchange_stats.items()},
@@ -237,7 +232,7 @@ class Driver:
     def execute(self, node: P.PlanNode) -> DeviceTable:
         """Run the plan; return the result as one device-resident table."""
         try:
-            with self._kernel_scope():
+            with tracing.span("repro.driver.execute"), self._kernel_scope():
                 stream = self._stream(node)
                 table = self._materialize(stream)
             self._harvest_feedback()
@@ -249,10 +244,13 @@ class Driver:
         """Run the plan; return valid rows as host numpy columns
         (deduplicated to worker 0 for replicated results)."""
         try:
-            with self._kernel_scope():
-                stream = self._stream(node)
-                table = self._materialize_table(stream.batches)
-            out = self._collect_host(stream, table)
+            with tracing.span("repro.driver.execute"):
+                with self._kernel_scope():
+                    stream = self._stream(node)
+                    table = self._materialize_table(stream.batches)
+                # the blocking device-to-host copy of the result
+                with tracing.span("repro.driver.to_host"):
+                    out = self._collect_host(stream, table)
             self._harvest_feedback()
             return out
         finally:
@@ -267,7 +265,7 @@ class Driver:
         compiled executable); None sizes it to the group."""
         from . import batch   # lazy: batch imports operators/fused
         try:
-            with self._kernel_scope():
+            with tracing.span("repro.driver.execute"), self._kernel_scope():
                 return batch.run_batch(self, shapes, lanes=lanes)
         finally:
             self._close_spill()
@@ -316,22 +314,22 @@ class Driver:
 
     def _run_pipeline(self, op: ops.Operator, stream: Iterator[DeviceTable]
                       ) -> Iterator[DeviceTable]:
+        # each operator call is spanned and timed on its own, never across
+        # a yield: upstream pulls and the consumer's work stay outside
         wrapped = self._maybe_host_wrap(op)
-        t0 = time.perf_counter()
+        secs = self.op_host_seconds
         op.open()
         for batch in stream:
             for pre in wrapped["pre"]:
-                batch = pre.add_input(batch)[0]
-            for out in op.add_input(batch):
+                batch = ops.call_op(pre, "add_input", secs, batch)[0]
+            for out in ops.call_op(op, "add_input", secs, batch):
                 for post in wrapped["post"]:
-                    out = post.add_input(out)[0]
+                    out = ops.call_op(post, "add_input", secs, out)[0]
                 yield out
-        for out in op.finish():
+        for out in ops.call_op(op, "finish", secs):
             for post in wrapped["post"]:
-                out = post.add_input(out)[0]
+                out = ops.call_op(post, "add_input", secs, out)[0]
             yield out
-        self.op_seconds[op.name] = (self.op_seconds.get(op.name, 0.0)
-                                    + time.perf_counter() - t0)
 
     def _maybe_host_wrap(self, op: ops.Operator):
         if op.name in self.ctx.host_only_ops:
@@ -359,22 +357,25 @@ class Driver:
     def _repartition(self, table: DeviceTable, keys: Sequence[str],
                      label: str = "repartition") -> DeviceTable:
         return self._tracked(
-            f"{label}({','.join(keys)})",
+            "repro.exchange.repartition", f"{label}({','.join(keys)})",
             lambda: self.ctx.exchange.repartition(
                 self._maybe_stage(table), tuple(keys), self._w))
 
     def _broadcast(self, table: DeviceTable,
                    label: str = "broadcast") -> DeviceTable:
         return self._tracked(
-            label, lambda: self.ctx.exchange.broadcast(
+            "repro.exchange.broadcast", label,
+            lambda: self.ctx.exchange.broadcast(
                 self._maybe_stage(table), self._w))
 
-    def _tracked(self, label: str, fn):
-        """Run one exchange, recording its stats delta as a fragment entry
-        (surfaced through ``Session.explain(analyze=True)``)."""
+    def _tracked(self, span: str, label: str, fn):
+        """Run one exchange under the span ``span``, recording its stats
+        delta as a fragment entry (surfaced through
+        ``Session.explain(analyze=True)``)."""
         st = self.ctx.exchange.stats
         before = dataclasses.replace(st)
-        out = fn()
+        with tracing.span(span, fragment=label):
+            out = fn()
         self.exchange_stats[f"#{self._frag_seq} {label}"] = {
             "rounds": st.rounds - before.rounds,
             "rows_moved": st.rows_moved - before.rows_moved,
@@ -485,7 +486,8 @@ class Driver:
                                  prefetch_depth=self.ctx.prefetch_depth,
                                  sharding=self.ctx.worker_sharding(),
                                  stats=stats, **kwargs)
-            scan = StreamingScan(node.table, morsels, stats, self.op_seconds)
+            scan = StreamingScan(node.table, morsels, stats,
+                                 self.op_host_seconds)
             if node.filter is not None:
                 fp = ops.FilterProject(node.filter)
                 if fp.name in self.ctx.host_only_ops:
@@ -649,7 +651,7 @@ class Driver:
                     reservation=granted)
                 join.open()
                 join.add_build(build)
-                join.seal_build()
+                ops.call_op(join, "seal_build", self.op_host_seconds)
                 del build   # partitioned into the spill hierarchy
                 out = self._run_pipeline(join, probe_batches)
                 return Stream(self._release_after(out, op_key), dist)
@@ -659,15 +661,15 @@ class Driver:
                             node.max_matches, build_rows=node.build_rows)
         join.open()
         join.add_build(build)
-        join.seal_build()
+        ops.call_op(join, "seal_build", self.op_host_seconds)
         if (probe_scan is not None and join._hash_state is not None
                 and not join._multi
                 and join.name not in self.ctx.host_only_ops):
             # fuse the single-match probe into the scan's per-morsel
             # pipeline: the iteration-start collapse folds it (plus any
             # preceding fused filter/project stages) into one Pallas
-            # dispatch per morsel. The join's time folds into the
-            # StreamingScan entry of op_seconds; the returned stream drops
+            # dispatch per morsel, timed under the fused operator's name
+            # in op_host_seconds; the returned stream drops
             # the scan so downstream stages keep their own dispatches
             # (fusing past a join would also skew its feedback counts).
             probe_scan.fuse(join)

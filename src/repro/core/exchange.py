@@ -27,7 +27,6 @@ paper §3.3.2) and count-based flow control.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import pickle
 import time
 from typing import List, Optional, Sequence
@@ -39,7 +38,15 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..kernels import ops as kernel_ops
 from . import relational as rel
+from . import tracing
 from .table import DeviceTable
+
+
+def _jit(**jit_kwargs):
+    """``jax.jit`` of an exchange program, named ``jit_exchange__<fn>``."""
+    def deco(fn):
+        return jax.jit(tracing.program("exchange", fn), **jit_kwargs)
+    return deco
 
 
 @dataclasses.dataclass
@@ -105,7 +112,7 @@ def _row_bytes(table: DeviceTable) -> int:
 # device-side partitioning programs (shared by both protocols' accounting)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+@_jit(static_argnums=(1, 2, 3))
 def _partition_counts(table: DeviceTable, key_names, num_workers: int,
                       backend: str = "jnp"):
     """Metadata phase: rows each src worker holds for each dst partition.
@@ -126,7 +133,7 @@ def _partition_counts(table: DeviceTable, key_names, num_workers: int,
     return jax.vmap(per_worker)(table)          # [W_src, W_dst]
 
 
-@functools.partial(jax.jit, static_argnums=(1,))
+@_jit(static_argnums=(1,))
 def _compact_stacked(table: DeviceTable, cap: int) -> DeviceTable:
     """Vector compaction (paper §3.3.2): per worker, move valid rows to the
     front and truncate to ``cap`` slots. Exchanges call this with ``cap``
@@ -171,7 +178,7 @@ def maybe_compact(table: DeviceTable) -> DeviceTable:
     return table
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+@_jit(static_argnums=(1, 2, 3))
 def _partition_layout_table(table: DeviceTable, key_names, num_workers: int,
                             part_cap: int) -> DeviceTable:
     """Data phase step 1: scatter rows into [W_dst, part_cap] send buffers."""
@@ -190,7 +197,7 @@ def _partition_layout_table(table: DeviceTable, key_names, num_workers: int,
     return jax.vmap(per_worker)(table)          # leaves [W_src, W_dst, cap, ...]
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+@_jit(static_argnums=(1, 2, 3))
 def _repartition_fused(table: DeviceTable, key_names, num_workers: int,
                        out_cap: int) -> DeviceTable:
     """Single-device fast path: the whole shuffle as index arithmetic plus
@@ -234,7 +241,7 @@ def _repartition_fused(table: DeviceTable, key_names, num_workers: int,
     return DeviceTable(cols, out_valid, table.schema)
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2))
+@_jit(static_argnums=(1, 2))
 def _broadcast_fused(table: DeviceTable, num_workers: int,
                      out_cap: int) -> DeviceTable:
     """Single-device fast path for broadcast: compact all valid rows with
@@ -332,7 +339,7 @@ class ICIExchange(ExchangeProtocol):
         return jax.tree.map(
             lambda x: jax.lax.with_sharding_constraint(x, spec), tree)
 
-    @functools.partial(jax.jit, static_argnums=(0, 2, 3))
+    @_jit(static_argnums=(0, 2, 3))
     def _exchange_data(self, staged: DeviceTable, num_workers: int,
                        part_cap: int) -> DeviceTable:
         staged = self._constrain(staged)
@@ -379,7 +386,7 @@ class ICIExchange(ExchangeProtocol):
         self.stats.seconds += time.perf_counter() - t0
         return out
 
-    @functools.partial(jax.jit, static_argnums=(0, 2))
+    @_jit(static_argnums=(0, 2))
     def _broadcast_data(self, table: DeviceTable, num_workers: int):
         table = self._constrain(table)
         cap = table.validity.shape[1]

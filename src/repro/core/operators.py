@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import functools
 import itertools
+import time
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -35,6 +36,7 @@ from ..kernels import ops as kernel_ops
 from . import dtypes as dt
 from . import fused
 from . import relational as rel
+from . import tracing
 from .expr import Expr
 from .table import DeviceTable, concat_tables
 
@@ -56,8 +58,11 @@ def clear_compile_caches() -> None:
         cache.cache_clear()
 
 
-def table_op(n_tables: int = 1):
+def table_op(n_tables: int = 1, *, family: str):
     """Wrap fn(*tables, *statics) with jit + optional worker-axis vmap.
+
+    ``family`` (one of ``tracing.FAMILIES``) names the program: its XLA
+    module reads ``jit_<family>__<fn>`` in a profiler trace.
 
     The compile cache additionally keys on the active kernel backend
     (``kernels.ops.current_backend``) and on the input tables' leaf
@@ -74,26 +79,35 @@ def table_op(n_tables: int = 1):
         @functools.lru_cache(maxsize=None)
         def compiled(statics, stacked, spec, backend):
             del spec  # one cache entry (and used-set) per specialization
-            body = lambda *tabs: fn(*tabs, *statics)
+
+            def body(*tabs):
+                return fn(*tabs, *statics)
+
+            body.__name__ = fn.__name__
+            body = tracing.program(family, body)
             used: set = set()
             return jax.jit(jax.vmap(body) if stacked else body), used
 
         _OP_CACHES.append(compiled)
 
+        def program_for(*args):
+            """The jitted program (and its kernel set) a call with
+            ``args`` runs; it takes the tables alone."""
+            tables, statics = args[:n_tables], args[n_tables:]
+            return compiled(tuple(statics), _is_stacked(tables[0]),
+                            _table_spec(tables), kernel_ops.current_backend())
+
         @functools.wraps(fn)
         def wrapper(*args):
-            tables, statics = args[:n_tables], args[n_tables:]
-            stacked = _is_stacked(tables[0])
-            jitted, used = compiled(tuple(statics), stacked,
-                                    _table_spec(tables),
-                                    kernel_ops.current_backend())
+            jitted, used = program_for(*args)
             with kernel_ops.record_kernels(used):
-                out = jitted(*tables)
+                out = jitted(*args[:n_tables])
             for kind in kernel_ops.kernel_snapshot(used):
                 kernel_ops.count_dispatch(kind)
             return out
 
         wrapper.raw = fn
+        wrapper.program = program_for
         return wrapper
 
     return deco
@@ -125,6 +139,12 @@ class Operator:
     name = "operator"
     is_device = True     # has a "cuDF version" (device implementation)
 
+    def __init_subclass__(cls, **kwargs):
+        super().__init_subclass__(**kwargs)
+        # span names built once per class: calls format nothing
+        cls.spans = {phase: f"repro.op.{cls.name}.{phase}"
+                     for phase in ("add_input", "finish", "seal_build")}
+
     def open(self) -> None:
         """Acquire per-query state; called once before any input."""
         pass
@@ -136,6 +156,24 @@ class Operator:
     def finish(self) -> List[DeviceTable]:
         """Flush accumulated state at end of input (blocking operators)."""
         return []
+
+
+def timed(span: str, name: str, seconds: dict, fn, *args):
+    """``fn(*args)`` under the span ``span``; its host seconds are added to
+    ``seconds[name]``."""
+    t0 = time.perf_counter()
+    with tracing.span(span):
+        out = fn(*args)
+    seconds[name] = seconds.get(name, 0.0) + time.perf_counter() - t0
+    return out
+
+
+def call_op(op: Operator, phase: str, seconds: dict, *args):
+    """``op.<phase>(*args)`` under the span ``repro.op.<name>.<phase>``,
+    timed into ``seconds[op.name]``: host self time of the operator's own
+    call, i.e. dispatch, not device time."""
+    return timed(op.spans[phase], op.name, seconds, getattr(op, phase),
+                 *args)
 
 
 # ---------------------------------------------------------------------------
@@ -153,8 +191,11 @@ class Pipeline(Operator):
 
     name = "Pipeline"
 
-    def __init__(self, ops_: Sequence[Operator] = ()):
+    def __init__(self, ops_: Sequence[Operator] = (),
+                 seconds: Optional[dict] = None):
         self.ops: List[Operator] = list(ops_)
+        # per operator name, host seconds of its calls (``call_op``)
+        self.seconds = seconds if seconds is not None else {}
 
     def open(self):
         for op in self.ops:
@@ -163,7 +204,8 @@ class Pipeline(Operator):
     def add_input(self, batch):
         outs = [batch]
         for op in self.ops:
-            outs = [o for b in outs for o in op.add_input(b)]
+            outs = [o for b in outs
+                    for o in call_op(op, "add_input", self.seconds, b)]
         return outs
 
     def finish(self):
@@ -171,8 +213,8 @@ class Pipeline(Operator):
         for op in self.ops:
             fed: List[DeviceTable] = []
             for b in carry:
-                fed.extend(op.add_input(b))
-            fed.extend(op.finish())
+                fed.extend(call_op(op, "add_input", self.seconds, b))
+            fed.extend(call_op(op, "finish", self.seconds))
             carry = fed
         return carry
 
@@ -181,7 +223,7 @@ class Pipeline(Operator):
 # FilterProject
 # ---------------------------------------------------------------------------
 
-@table_op()
+@table_op(family="scan")
 def _filter_project(table: DeviceTable, filter_expr, projections, compact: bool):
     if filter_expr is not None:
         table = table.filter(filter_expr.evaluate(table))
@@ -242,7 +284,7 @@ def merge_specs(specs: Sequence[AggSpec]) -> Tuple[AggSpec, ...]:
     return tuple((out, _MERGE_KIND[kind], out) for out, kind, _ in specs)
 
 
-@table_op()
+@table_op(family="agg")
 def _aggregate(table: DeviceTable, group_keys, specs, max_groups: int):
     key_cols = [table.columns[k] for k in group_keys]
     cols, schema = {}, {}
@@ -285,7 +327,7 @@ def _aggregate(table: DeviceTable, group_keys, specs, max_groups: int):
     return DeviceTable(cols, validity, schema)
 
 
-@table_op()
+@table_op(family="agg")
 def _finalize_avg(table: DeviceTable, user_specs):
     cols = dict(table.columns)
     schema = dict(table.schema)
@@ -420,7 +462,7 @@ MAX_HASH_TABLE_SLOTS = 1 << 18
 EMPTY_KEY = -1
 
 
-@table_op()
+@table_op(family="join")
 def _build_join_table(build: DeviceTable, build_keys):
     key, _ = rel.join_key([build.columns[k] for k in build_keys])
     return rel.join_build(key, build.validity)
@@ -439,7 +481,7 @@ def _join_probe_key(table: DeviceTable, key_names, pack):
     return key
 
 
-@table_op()
+@table_op(family="join")
 def _build_hash_table(build: DeviceTable, build_keys, table_size: int, pack):
     key = _join_probe_key(build, build_keys, pack)
     rows = jnp.arange(key.shape[0], dtype=jnp.int32)
@@ -532,7 +574,7 @@ def _attach_build_payload(probe: DeviceTable, build: DeviceTable, found,
     return DeviceTable(cols, found, schema)
 
 
-@table_op(n_tables=2)
+@table_op(n_tables=2, family="join")
 def _probe_join_pallas(probe: DeviceTable, hash_state, probe_keys,
                        build_payload, join_type: str, max_probes: int, pack):
     """Open-addressing probe (Pallas ``hash_probe``): one table lookup per
@@ -553,7 +595,7 @@ def _probe_join_pallas(probe: DeviceTable, hash_state, probe_keys,
                                  join_type)
 
 
-@table_op(n_tables=2)
+@table_op(n_tables=2, family="join")
 def _probe_join_pallas_multi(probe: DeviceTable, hash_state, probe_keys,
                              build_payload, join_type: str, max_probes: int,
                              max_matches: int, pack):
@@ -622,7 +664,7 @@ def _expand_join_output(probe: DeviceTable, build_table: DeviceTable,
     return DeviceTable(cols, out_valid, schema)
 
 
-@table_op(n_tables=2)
+@table_op(n_tables=2, family="join")
 def _probe_join(probe: DeviceTable, build_state, probe_keys, build_keys,
                 build_payload, join_type: str, max_matches: int, exact: bool):
     build_table, bt = build_state
@@ -786,7 +828,7 @@ def _pow2(n: int) -> int:
     return 1 << max(int(np.ceil(np.log2(max(int(n), 1)))), 0)
 
 
-@table_op()
+@table_op(family="join")
 def _grace_pids(table: DeviceTable, keys, num_parts: int):
     """Radix-partition ids for grace-join fan-out — the exchange's
     partitioner (``rel.partition_ids``) with its metadata histogram
@@ -993,7 +1035,7 @@ class GraceHashJoin(Operator):
         return outs
 
 
-@table_op()
+@table_op(family="scan")
 def _compact(table: DeviceTable):
     return table.compact()
 
@@ -1007,13 +1049,13 @@ def compact_table(table: DeviceTable) -> DeviceTable:
 # FusedMorsel: one Pallas dispatch per morsel (filter -> project -> probe)
 # ---------------------------------------------------------------------------
 
-@table_op()
+@table_op(family="scan")
 def _fused_morsel(table: DeviceTable, stages):
     out, _, _ = fused.fused_morsel_program(table, stages)
     return out
 
 
-@table_op(n_tables=2)
+@table_op(n_tables=2, family="join")
 def _fused_morsel_probe(table: DeviceTable, hash_state, stages, probe_keys,
                         build_payload, join_type: str, max_probes: int, pack):
     build, tk, tv = hash_state
@@ -1099,7 +1141,7 @@ def fuse_morsel_pipeline(pipe: Pipeline) -> None:
     pipe.ops = new_ops
 
 
-@table_op()
+@table_op(family="sort")
 def _head(table: DeviceTable, n: int):
     c = table.compact()
     return c.filter(jnp.arange(c.capacity) < n)
@@ -1109,7 +1151,7 @@ def _head(table: DeviceTable, n: int):
 # OrderBy / Limit
 # ---------------------------------------------------------------------------
 
-@table_op()
+@table_op(family="sort")
 def _order_by(table: DeviceTable, keys, descending, limit):
     order = rel.lexsort([table.columns[k] for k in keys], table.validity,
                         list(descending))
@@ -1172,7 +1214,7 @@ class Limit(Operator):
 # Scalar broadcast (uncorrelated scalar subqueries: Q11, Q15, Q22)
 # ---------------------------------------------------------------------------
 
-@table_op(n_tables=2)
+@table_op(n_tables=2, family="scan")
 def _attach_scalar(batch: DeviceTable, scalar: DeviceTable, columns):
     s = scalar.compact()
     out = batch

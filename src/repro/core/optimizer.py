@@ -43,6 +43,7 @@ from typing import Dict, FrozenSet, List, Optional, Sequence, Set
 
 from . import dtypes as dt
 from . import plan as P
+from . import tracing
 from .expr import BinaryOp, ColumnRef, Expr
 
 # max_groups/max_matches are static array capacities; when the provable
@@ -982,8 +983,9 @@ DEFAULT_RULES = (push_filters, prune_columns, reorder_joins,
 def optimize(plan: P.PlanNode, catalog, rules=DEFAULT_RULES,
              config: OptimizerConfig = DEFAULT_CONFIG) -> P.PlanNode:
     """Run the rule pipeline; the input tree is never mutated."""
-    for rule in rules:
-        plan = rule(plan, catalog, config)
+    with tracing.span("repro.plan.optimize"):
+        for rule in rules:
+            plan = rule(plan, catalog, config)
     return plan
 
 

@@ -61,6 +61,7 @@ from typing import Dict, List, Optional, Tuple
 from ..kernels import ops as kernel_ops
 from . import batch as _batch
 from . import plan as P
+from . import tracing
 from ..kernels import segmented_agg as _segagg
 from .driver import Driver, empty_executor_stats
 from .feedback import qerror
@@ -346,6 +347,17 @@ class QueryScheduler:
         even when ``SchedulerConfig.batching`` is on (it has no effect
         when the config flag is off — batching is strictly opt-in).
         """
+        query_id = next(self._ids)
+        with tracing.query(query_id), tracing.span("repro.sched.submit"):
+            return self._admit(query_id, plan, priority, sql, num_workers,
+                               kernel_backend, optimize, feedback, batching)
+
+    def _admit(self, query_id: int, plan: P.PlanNode, priority: int,
+               sql: Optional[str], num_workers: Optional[int],
+               kernel_backend: Optional[str], optimize: Optional[bool],
+               feedback: Optional[object],
+               batching: Optional[bool]) -> QueryHandle:
+        """``submit``'s body: optimize, estimate memory, admit."""
         # the kernel backend is resolved ONCE, here at submit time (the
         # per-query override, else the session's setting, else the
         # submitting thread's use_backend() scope / env default), and
@@ -390,7 +402,7 @@ class QueryScheduler:
         # result cache first: a hit skips optimization entirely
         cached = self.result_cache.get(key, self.session.catalog)
         if cached is not None:
-            handle = QueryHandle(next(self._ids), plan, priority, 0)
+            handle = QueryHandle(query_id, plan, priority, 0)
             handle.kernel_backend = backend
             handle.num_workers = w
             handle.cache_hit = True
@@ -421,7 +433,7 @@ class QueryScheduler:
         # over-budget queries are admitted with spilling: they charge the
         # whole budget (running effectively alone) and degrade through the
         # host/disk tiers instead of being refused
-        handle = QueryHandle(next(self._ids), optimized, priority,
+        handle = QueryHandle(query_id, optimized, priority,
                              min(est, self.config.memory_budget))
         handle.footprint = est
         handle.memory_breakdown = breakdown
@@ -704,7 +716,12 @@ class QueryScheduler:
         per-member results (and per-query stats attribution) back onto
         each handle. Any stacked failure falls back to per-member solo
         execution — a query that would succeed alone must never receive
-        a batched error."""
+        a batched error. Its spans carry the first member's query id."""
+        with tracing.query(members[0].query_id), \
+                tracing.span("repro.sched.run", queries=len(members)):
+            self._run_batch(members)
+
+    def _run_batch(self, members: List[QueryHandle]) -> None:
         t_launch = time.perf_counter()
         for m in members:
             m.started_at = t_launch
@@ -753,6 +770,10 @@ class QueryScheduler:
 
     def _execute(self, handle: QueryHandle) -> None:
         """Run one admitted query on this worker thread's own Driver."""
+        with tracing.query(handle.query_id), tracing.span("repro.sched.run"):
+            self._run_solo(handle)
+
+    def _run_solo(self, handle: QueryHandle) -> None:
         handle.started_at = time.perf_counter()
         try:
             sess = self.session

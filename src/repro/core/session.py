@@ -15,6 +15,7 @@ from typing import Dict, Iterator, Optional
 
 import numpy as np
 
+from . import tracing
 from .driver import Driver, ExecutionContext, empty_executor_stats
 from .exchange import ExchangeProtocol, ICIExchange
 from .plan import PlanNode
@@ -436,7 +437,8 @@ class Session:
                            options=options).result()
 
     def executor_stats(self) -> Dict[str, object]:
-        """Stats from the most recent ``execute`` (scan + operator timings).
+        """Stats from the most recent ``execute`` (scan counters, operator
+        host times, kernel dispatch counts).
 
         Before any query has run this returns the same *shape* a Driver
         reports — every key present, empty values — so callers can index
@@ -481,7 +483,8 @@ class Session:
         ``ExecutionOptions`` that ``collect``/``submit`` pick up.
         """
         from .sql import lower_sql
-        qb = lower_sql(text, self.catalog, session=self, dialect=dialect)
+        with tracing.span("repro.plan.sql"):
+            qb = lower_sql(text, self.catalog, session=self, dialect=dialect)
         qb._options = options
         return qb
 
@@ -512,7 +515,8 @@ class Session:
         With ``analyze=True`` the (optimized) plan is also executed and the
         executor's per-table scan stats -- bytes read, bytes transferred,
         chunks skipped by zone maps, prefetch-overlap fraction -- plus
-        operator timings, per-fragment exchange stats (rows/bytes moved,
+        each operator's host dispatch seconds (``op_host_seconds``: host
+        time of its own calls, not device time), per-fragment exchange stats (rows/bytes moved,
         host-staged bytes per Repartition/Broadcast), the per-operator
         memory-footprint breakdown, and -- when a ``device_budget`` is set
         -- the spill-cost estimate and observed per-tier spill counters
@@ -538,8 +542,8 @@ class Session:
                 f"bytes_read={s['bytes_read']} "
                 f"bytes_transferred={s['bytes_transferred']} "
                 f"prefetch_overlap={s['prefetch_overlap']:.2f}")
-        for op, sec in sorted(stats.get("op_seconds", {}).items()):
-            lines.append(f"op {op}: {sec:.4f}s")
+        for op, sec in sorted(stats.get("op_host_seconds", {}).items()):
+            lines.append(f"op_host_seconds {op}: {sec:.4f}s")
         kd = stats.get("kernel_dispatch") or {}
         if kd:
             lines.append(

@@ -23,6 +23,7 @@ that generator synchronously or through a prefetcher respectively.
 
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 import queue
 import threading
@@ -33,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import tracing
 from .table import DeviceTable
 
 
@@ -45,13 +47,15 @@ class ScanStats:
     chunks_total: int = 0        # chunks considered by the scan
     chunks_skipped: int = 0      # chunks pruned by zone-map stats
     morsels: int = 0             # morsels produced
-    read_seconds: float = 0.0    # producer: storage read + host->device put
+    # producer: storage read, host-budget wait and the host->device put's
+    # *enqueue* (the transfer is asynchronous; its completion is not here)
+    read_seconds: float = 0.0
     wait_seconds: float = 0.0    # consumer: blocked waiting on the queue
-    compute_seconds: float = 0.0 # consumer: time between dequeues
 
     @property
     def prefetch_overlap(self) -> float:
-        """Fraction of read+transfer time hidden behind consumer compute."""
+        """Fraction of the producer's read + put-enqueue time hidden behind
+        the consumer (1 - wait / read)."""
         if self.read_seconds <= 0.0:
             return 0.0
         return max(0.0, 1.0 - self.wait_seconds / self.read_seconds)
@@ -169,8 +173,11 @@ class MorselPrefetcher:
             host_budget = HostMemoryBudget(max_bytes)
         self._budget = host_budget
         self._closed = threading.Event()
-        self._thread = threading.Thread(target=self._produce, daemon=True,
-                                        name="morsel-prefetch")
+        # the producer runs in a copy of the creating thread's context, so
+        # its spans carry the query id the scheduler set there
+        self._thread = threading.Thread(
+            target=contextvars.copy_context().run, args=(self._produce,),
+            daemon=True, name="morsel-prefetch")
 
     # -- producer (background thread) ---------------------------------------
     def _put(self, item) -> bool:
@@ -188,7 +195,8 @@ class MorselPrefetcher:
             while not self._closed.is_set():
                 t0 = time.perf_counter()
                 try:
-                    host = next(it)
+                    with tracing.span("repro.scan.read"):
+                        host = next(it)
                 except StopIteration:
                     break
                 nbytes = host.nbytes()   # HostMorsel or DeviceTable alike
@@ -198,7 +206,8 @@ class MorselPrefetcher:
                     if not self._budget.acquire(nbytes,
                                                 stop=self._closed.is_set):
                         return
-                table = morsel_to_device(host, self._sharding)
+                with tracing.span("repro.scan.put"):
+                    table = morsel_to_device(host, self._sharding)
                 self.stats.read_seconds += time.perf_counter() - t0
                 self.stats.bytes_transferred += nbytes
                 self.stats.morsels += 1
@@ -227,15 +236,11 @@ class MorselPrefetcher:
     def __iter__(self) -> Iterator[DeviceTable]:
         self._thread.start()
         try:
-            last = None
             while True:
                 t0 = time.perf_counter()
-                item = self._q.get()
-                now = time.perf_counter()
-                self.stats.wait_seconds += now - t0
-                if last is not None:
-                    self.stats.compute_seconds += t0 - last
-                last = now
+                with tracing.span("repro.scan.wait"):
+                    item = self._q.get()
+                self.stats.wait_seconds += time.perf_counter() - t0
                 if item is _SENTINEL:
                     return
                 if isinstance(item, BaseException):
