@@ -160,6 +160,7 @@ def empty_executor_stats() -> Dict[str, object]:
         "exchanges": {},
         "kernel_backend": "",
         "kernel_dispatch": {},
+        "agg": {},
         "spill": {},
         "spill_staged_exchanges": 0,
         "feedback": {},
@@ -186,6 +187,8 @@ class Driver:
         # per-query kernel dispatch counts (kind -> executions of a pallas
         # kernel: 'probe', 'agg', 'compact', 'partition', 'build')
         self.kernel_dispatch: Dict[str, int] = {}
+        # per-query HashAggregation counters: partials, merges, merge_rows
+        self.agg_stats: Dict[str, int] = dict.fromkeys(ops.AGG_COUNTERS, 0)
         # per-fragment exchange stats: one entry per exchange executed, in
         # execution order ("#0 Repartition(l_orderkey)" -> counter deltas)
         self.exchange_stats: Dict[str, Dict[str, float]] = {}
@@ -201,7 +204,8 @@ class Driver:
 
     def executor_stats(self) -> Dict[str, object]:
         """Per-query executor stats: scan counters, operator host times,
-        kernel backend + dispatch counts, per-fragment exchange counters
+        kernel backend + dispatch counts, aggregation merge counters
+        (``agg``), per-fragment exchange counters
         (rows/bytes moved, host staging), per-tier spill counters, and the
         feedback-store summary. Same key shape as
         ``empty_executor_stats()``."""
@@ -213,6 +217,7 @@ class Driver:
             "exchanges": {k: dict(v) for k, v in self.exchange_stats.items()},
             "kernel_backend": self.ctx.kernel_backend,
             "kernel_dispatch": dict(self.kernel_dispatch),
+            "agg": dict(self.agg_stats),
             "spill": (self.ctx.spill.stats.summary()
                       if self.ctx.spill is not None else {}),
             "spill_staged_exchanges": self.spill_staged_exchanges,
@@ -221,11 +226,13 @@ class Driver:
         }
 
     def _kernel_scope(self):
-        """Backend + dispatch-accounting scope one query runs under."""
+        """Backend + dispatch- and merge-accounting scope one query runs
+        under."""
         scope = contextlib.ExitStack()
         scope.enter_context(kernel_ops.use_backend(self.ctx.kernel_backend))
         scope.enter_context(
             kernel_ops.collect_dispatches(self.kernel_dispatch))
+        scope.enter_context(ops.collect_agg_stats(self.agg_stats))
         return scope
 
     # -- public API ----------------------------------------------------------

@@ -23,10 +23,12 @@ one vmap lane per mesh worker here).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import itertools
+import threading
 import time
-from typing import List, Optional, Sequence, Tuple
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -341,13 +343,56 @@ def _finalize_avg(table: DeviceTable, user_specs):
     return DeviceTable(cols, table.validity, schema)
 
 
+# per-query aggregation counters (``executor_stats()["agg"]``): the driver
+# opens a scope around each query, as it does for kernel dispatches, and
+# HashAggregation reports into it from static shapes (no device sync)
+AGG_COUNTERS = ("partials", "merges", "merge_rows")
+_agg_tls = threading.local()
+
+
+@contextlib.contextmanager
+def collect_agg_stats(counts: Dict[str, int]) -> Iterator[None]:
+    """Add HashAggregation's counters (``AGG_COUNTERS``) into ``counts``
+    for the duration of the scope, on this thread."""
+    stack = getattr(_agg_tls, "stack", None)
+    if stack is None:
+        stack = _agg_tls.stack = []
+    stack.append(counts)
+    try:
+        yield
+    finally:
+        stack.pop()
+
+
+def _count_agg(name: str, n: int = 1) -> None:
+    for counts in getattr(_agg_tls, "stack", ()):
+        counts[name] = counts.get(name, 0) + n
+
+
+def _rows(table: DeviceTable) -> int:
+    """Static row slots of a local or worker-stacked table (per worker)."""
+    return int(table.validity.shape[-1])
+
+
 class HashAggregation(Operator):
     """Concatenation-based streaming aggregation (paper §3.2).
 
     cuDF has no streaming groupby, so the paper aggregates each batch,
-    concatenates with the running partial result and re-aggregates until a
-    size threshold triggers emission. Reproduced exactly: per-batch partial
-    agg (sort-based on TPU), concat with the accumulator, re-aggregate.
+    concatenates the partial results and re-aggregates them once their
+    size reaches a threshold. Reproduced so: each batch is aggregated
+    (sort-based on TPU) into ``min(max_groups, batch rows)`` slots, since
+    a batch holds no more groups than rows, and held. The held tables, the
+    accumulator and the pending partials, are concatenated and
+    re-aggregated only once their slots reach ``2 * max_groups``, the size
+    of one accumulator-plus-partial merge; ``finish`` merges what is left
+    (a lone table needs no merge). Where ``emit_rows`` (partial mode) or a
+    spill flush point is set, the threshold drops to the smallest of them:
+    a merge has no more groups than the slots it reads, so every emission
+    and flush happens at the batch it would if each batch were merged.
+
+    Counted per query (``collect_agg_stats``): ``partials`` (batches
+    aggregated), ``merges`` (re-aggregations), ``merge_rows`` (slots fed to
+    merges).
 
     mode: 'partial'  emits partial columns (avg -> sum+cnt) for an exchange
           'final'    merges partial columns after an exchange
@@ -376,59 +421,73 @@ class HashAggregation(Operator):
         # finish() merges the flushed runs back in a final pass
         self.spill = spill
         self.spill_flush_groups = spill_flush_groups
+        self._merge_at = 2 * max_groups
+        if self._flushes():
+            self._merge_at = min(self._merge_at, spill_flush_groups)
+        if self._emits():
+            self._merge_at = min(self._merge_at, emit_rows)
         self._skey = f"agg{next(self._spill_seq)}"
         self._flushed: List[object] = []
-        self._acc: Optional[DeviceTable] = None
-        self._saw_input = False
+        # the accumulator (first, once a merge has run) and the pending
+        # partials
+        self._held: List[DeviceTable] = []
 
     def open(self):
-        self._acc = None
+        self._held = []
         self._flushed = []
-        self._saw_input = False
+
+    def _flushes(self) -> bool:
+        return self.spill is not None and self.spill_flush_groups is not None
+
+    def _emits(self) -> bool:
+        return self.emit_rows is not None and self.mode == "partial"
+
+    def _merge(self, tables: List[DeviceTable]) -> DeviceTable:
+        """The groups of ``tables`` in one table: concatenated and
+        re-aggregated, unless there is only one."""
+        if len(tables) == 1:
+            return tables[0]
+        merged = concat_tables(tables)
+        rows = _rows(merged)
+        _count_agg("merges")
+        _count_agg("merge_rows", rows)
+        return _aggregate(merged, self.group_keys, merge_specs(self.specs),
+                          min(self.max_groups, rows))
 
     def add_input(self, batch):
-        self._saw_input = True
-        part = _aggregate(batch, self.group_keys, self.specs, self.max_groups)
-        if self._acc is None:
-            self._acc = part
-        else:
-            merged = concat_tables([self._acc, part])
-            self._acc = _aggregate(merged, self.group_keys, merge_specs(self.specs),
-                                   self.max_groups)
-        if (self.spill is not None and self.spill_flush_groups is not None
-                and int(self._acc.num_valid()) >= self.spill_flush_groups):
-            key = (self._skey, len(self._flushed))
-            self.spill.spill_table(key, self._acc)
-            self._flushed.append(key)
-            self._acc = None
+        self._held.append(_aggregate(batch, self.group_keys, self.specs,
+                                     min(self.max_groups, _rows(batch))))
+        _count_agg("partials")
+        if sum(_rows(t) for t in self._held) < self._merge_at:
             return []
-        if (self.emit_rows is not None and self.mode == "partial"
-                and int(self._acc.num_valid()) >= self.emit_rows):
-            out, self._acc = self._acc, None
-            return [out]
+        acc = self._merge(self._held)
+        self._held = []
+        if (self._flushes()
+                and int(acc.num_valid()) >= self.spill_flush_groups):
+            key = (self._skey, len(self._flushed))
+            self.spill.spill_table(key, acc)
+            self._flushed.append(key)
+            return []
+        if self._emits() and int(acc.num_valid()) >= self.emit_rows:
+            return [acc]
+        self._held = [acc]
         return []
 
     def finish(self):
-        if self._flushed:
-            # final pass: restore the flushed runs one at a time and merge
-            # each into the accumulator (device working set stays at two
-            # max_groups tables regardless of how many runs spilled)
-            acc = self._acc
-            for key in self._flushed:
-                run = self.spill.restore(key)
-                if acc is None:
-                    acc = run
-                else:
-                    acc = _aggregate(concat_tables([acc, run]),
-                                     self.group_keys,
-                                     merge_specs(self.specs), self.max_groups)
-            self._acc, self._flushed = acc, []
-        if self._acc is None:
+        acc = self._merge(self._held) if self._held else None
+        self._held = []
+        # restore the flushed runs one at a time and merge each into the
+        # accumulator (device working set stays at two max_groups tables
+        # regardless of how many runs spilled)
+        for key in self._flushed:
+            run = self.spill.restore(key)
+            acc = run if acc is None else self._merge([acc, run])
+        self._flushed = []
+        if acc is None:
             return []
-        out, self._acc = self._acc, None
         if self.mode in ("final", "single"):
-            out = _finalize_avg(out, self.user_specs)
-        return [out]
+            acc = _finalize_avg(acc, self.user_specs)
+        return [acc]
 
 
 class Distinct(Operator):

@@ -516,7 +516,9 @@ class Session:
         executor's per-table scan stats -- bytes read, bytes transferred,
         chunks skipped by zone maps, prefetch-overlap fraction -- plus
         each operator's host dispatch seconds (``op_host_seconds``: host
-        time of its own calls, not device time), per-fragment exchange stats (rows/bytes moved,
+        time of its own calls, not device time), the streaming
+        aggregation's partials, merges and rows merged (``agg``),
+        per-fragment exchange stats (rows/bytes moved,
         host-staged bytes per Repartition/Broadcast), the per-operator
         memory-footprint breakdown, and -- when a ``device_budget`` is set
         -- the spill-cost estimate and observed per-tier spill counters
@@ -549,6 +551,10 @@ class Session:
             lines.append(
                 f"kernels [{stats.get('kernel_backend')}]: "
                 + " ".join(f"{k}={v}" for k, v in sorted(kd.items())))
+        agg = stats.get("agg") or {}
+        if agg.get("partials"):
+            lines.append("agg: " + " ".join(f"{k}={v}"
+                                            for k, v in agg.items()))
         for frag, ex in stats.get("exchanges", {}).items():
             lines.append(
                 f"exchange {frag} [{stats.get('exchange_protocol')}]: "

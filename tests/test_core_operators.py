@@ -1,6 +1,7 @@
 """Unit + property tests for the core engine primitives and operators."""
 
 import numpy as np
+import pytest
 from _hypothesis_compat import given, settings, st
 
 import jax.numpy as jnp
@@ -9,6 +10,7 @@ from repro.core import dtypes as dt
 from repro.core import relational as rel
 from repro.core import operators as ops
 from repro.core.expr import col, prefix_code, year
+from repro.core.spill import SpillManager
 from repro.core.table import DeviceTable, concat_tables
 
 
@@ -229,6 +231,181 @@ def test_partial_emit_threshold_flow_control():
                                         _SCHEMA))
     emitted += agg.finish()
     assert len(emitted) >= 2                # streamed early at the threshold
+
+
+# streaming aggregation's merge policy, against numpy and against the
+# former policy of re-aggregating the accumulator with every batch
+
+_AGGS = [("s", "sum", "v"), ("c", "count", None), ("m", "max", "v")]
+_FINAL_SCHEMA = {"k": dt.INT32, "s": dt.FLOAT32, "c": dt.INT32,
+                 "m": dt.FLOAT32}
+
+
+def _merge_every_batch(batches, agg, on_merge=None):
+    """Output of the former policy: every batch's partial in
+    ``max_groups`` slots, merged into the accumulator at once;
+    ``on_merge(i, acc)`` after batch ``i`` may take the accumulator away
+    (returns None)."""
+    acc = None
+    for i, b in enumerate(batches):
+        part = ops._aggregate(b, agg.group_keys, agg.specs, agg.max_groups)
+        acc = part if acc is None else ops._aggregate(
+            concat_tables([acc, part]), agg.group_keys,
+            ops.merge_specs(agg.specs), agg.max_groups)
+        if on_merge is not None:
+            acc = on_merge(i, acc)
+    return acc
+
+
+def _agg_batches(rng, mode, max_groups, rows, n_batches, workers):
+    """``n_batches`` tables of ``rows`` slots (the last 3 dead) with keys
+    in ``[0, max_groups)``; worker-stacked ``[workers, rows]`` when
+    ``workers``; final mode gets partial columns."""
+    def one():
+        live = rows - 3
+        data = {"k": rng.integers(0, max_groups, live).astype(np.int32)}
+        if mode == "final":
+            data.update(s=rng.random(live).astype(np.float32),
+                        c=rng.integers(1, 5, live).astype(np.int32),
+                        m=rng.random(live).astype(np.float32))
+            return _table(data, _FINAL_SCHEMA, rows)
+        data["v"] = rng.random(live).astype(np.float32)
+        return _table(data, _SCHEMA, rows)
+
+    if not workers:
+        return [one() for _ in range(n_batches)]
+    out = []
+    for _ in range(n_batches):
+        ts = [one() for _ in range(workers)]
+        out.append(DeviceTable(
+            {n: jnp.stack([t.columns[n] for t in ts]) for n in ts[0].columns},
+            jnp.stack([t.validity for t in ts]), ts[0].schema))
+    return out
+
+
+def _by_key(cols, validity):
+    """``{key: (s, c, m)}`` of one worker's valid output rows."""
+    return {int(k): (float(s), int(c), float(m)) for k, s, c, m in zip(
+        *(np.asarray(cols[n])[validity] for n in ("k", "s", "c", "m")))}
+
+
+def _workers(table):
+    """Per worker, ``(columns, validity)`` as numpy."""
+    val = np.asarray(table.validity)
+    cols = {n: np.asarray(a) for n, a in table.columns.items()}
+    if val.ndim == 1:
+        return [(cols, val)]
+    return [({n: a[w] for n, a in cols.items()}, val[w])
+            for w in range(val.shape[0])]
+
+
+def _numpy_groups(batches, mode, worker):
+    """``{key: (s, c, m)}`` over one worker's valid input rows."""
+    parts = [_workers(b)[worker] for b in batches]
+    k = np.concatenate([c["k"][v] for c, v in parts])
+    if mode == "final":
+        s, c, m = (np.concatenate([cs[n][v] for cs, v in parts])
+                   for n in ("s", "c", "m"))
+    else:
+        s = m = np.concatenate([cs["v"][v] for cs, v in parts])
+        c = np.ones(len(k), np.int64)
+    return {int(u): (float(s[k == u].astype(np.float64).sum()),
+                     int(c[k == u].sum()), float(m[k == u].max()))
+            for u in np.unique(k)}
+
+
+def _assert_groups(got, want, rtol):
+    assert set(got) == set(want)
+    for key, (s, c, m) in want.items():
+        np.testing.assert_allclose(got[key][0], s, rtol=rtol)
+        assert got[key][1] == c and got[key][2] == m
+
+
+@pytest.mark.parametrize("mode", ["single", "partial", "final"])
+@pytest.mark.parametrize("max_groups,rows,n_batches,workers", [
+    (64, 16, 6, 0),     # partials wait under 2 x max_groups: one merge
+    (32, 32, 4, 0),     # batch of max_groups rows: a merge per batch
+    (16, 48, 3, 0),     # batch above max_groups rows: a merge per batch
+    (32, 16, 7, 0),     # merges at the threshold, and one at finish
+    (64, 16, 5, 2),     # worker-stacked, waiting
+    (16, 32, 3, 2),     # worker-stacked, a merge per batch
+])
+def test_streaming_aggregation_merges_at_threshold(
+        mode, max_groups, rows, n_batches, workers):
+    batches = _agg_batches(np.random.default_rng(max_groups + rows),
+                           mode, max_groups, rows, n_batches, workers)
+    agg = ops.HashAggregation(["k"], _AGGS, mode, max_groups=max_groups)
+    counts = {}
+    agg.open()
+    with ops.collect_agg_stats(counts):
+        for b in batches:
+            assert agg.add_input(b) == []
+        streamed = dict(counts)
+        out = agg.finish()[0]
+    assert counts["partials"] == n_batches
+    slots = min(max_groups, rows)
+    if n_batches * slots < 2 * max_groups:
+        assert streamed.get("merges", 0) == 0
+        assert counts["merges"] == 1
+        assert counts["merge_rows"] == n_batches * slots
+    elif rows >= max_groups:
+        assert streamed["merges"] == counts["merges"] == n_batches - 1
+        assert counts["merge_rows"] == (n_batches - 1) * 2 * max_groups
+    else:
+        assert 1 <= streamed["merges"] < counts["merges"] < n_batches - 1
+    former = _merge_every_batch(batches, agg)
+    for w, ((cols, val), (fcols, fval)) in enumerate(
+            zip(_workers(out), _workers(former))):
+        got = _by_key(cols, val)
+        _assert_groups(got, _numpy_groups(batches, mode, w), rtol=1e-5)
+        _assert_groups(got, _by_key(fcols, fval), rtol=1e-6)
+
+
+@pytest.mark.parametrize("point", ["spill", "emit"])
+def test_flush_and_emit_points_match_merging_every_batch(point, tmp_path):
+    """A spill flush or partial emission happens at the batch it did when
+    every batch was merged into the accumulator, with the same groups."""
+    max_groups, threshold = 64, 20
+    batches = _agg_batches(np.random.default_rng(7), "partial", max_groups,
+                           16, 8, 0)
+    if point == "spill":
+        mgr = SpillManager(device_budget=0, spill_dir=str(tmp_path))
+        kw = {"spill": mgr, "spill_flush_groups": threshold}
+    else:
+        kw = {"emit_rows": threshold}
+    agg = ops.HashAggregation(["k"], _AGGS, "partial", max_groups, **kw)
+    agg.open()
+    points, outs = [], []
+    for i, b in enumerate(batches):
+        flushed = len(agg._flushed)
+        emitted = agg.add_input(b)
+        if emitted or len(agg._flushed) > flushed:
+            points.append(i)
+        outs += emitted
+    outs += agg.finish()
+
+    former_points, former_outs = [], []
+
+    def on_merge(i, acc):
+        if int(acc.num_valid()) >= threshold:
+            former_points.append(i)
+            former_outs.append(acc)
+            return None
+        return acc
+
+    last = _merge_every_batch(batches, agg, on_merge)
+    assert points == former_points and len(points) >= 2
+    if point == "spill":
+        assert mgr.stats.summary()["host"]["spills"] == len(former_points)
+        got = _by_key(*_workers(outs[0])[0])
+        _assert_groups(got, _numpy_groups(batches, "partial", 0), rtol=1e-5)
+    else:
+        if last is not None:
+            former_outs.append(last)
+        assert len(outs) == len(former_outs)
+        for a, b in zip(outs, former_outs):
+            _assert_groups(_by_key(*_workers(a)[0]),
+                           _by_key(*_workers(b)[0]), rtol=1e-6)
 
 
 def test_join_types_against_numpy():

@@ -95,6 +95,34 @@ def test_query_spans_carry_one_query_id(tmp_path):
     assert sum(stats.values()) <= handle.latency
 
 
+@pytest.mark.parametrize("keys, merges", [
+    ("l_orderkey", "once"),          # groups bounded by the row count
+    ("l_returnflag", "per_morsel"),  # groups far below a morsel's rows
+])
+def test_agg_counters_per_query(keys, merges):
+    sess = Session(dbgen.load_catalog(sf=0.002), batch_rows=2048,
+                   scheduler_config=SchedulerConfig(plan_cache_size=0,
+                                                    cache_results=False))
+    qb = sess.sql(f"SELECT {keys}, sum(l_quantity) AS q FROM lineitem "
+                  f"GROUP BY {keys}")
+    handle = qb.submit()
+    handle.result()
+    sess.scheduler().close()
+    stats = handle.executor_stats
+    morsels = stats["tables"]["lineitem"]["morsels"]
+    agg = stats["agg"]
+    assert morsels >= 3 and agg["partials"] == morsels
+    if merges == "once":
+        # every partial's slots, the last morsel's fewer than 2048
+        assert agg["merges"] == 1
+        assert (morsels - 1) * 2048 < agg["merge_rows"] <= morsels * 2048
+    else:
+        assert agg["merges"] == morsels - 1
+    assert "agg: partials=%d merges=%d merge_rows=%d" % (
+        agg["partials"], agg["merges"], agg["merge_rows"]) in \
+        qb.explain(analyze=True)
+
+
 class _Sleeper(ops.Operator):
     """Passes batches through; each own call sleeps ``delay`` seconds."""
 
