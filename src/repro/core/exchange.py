@@ -166,12 +166,16 @@ def _pow2(n: int) -> int:
     return int(2 ** np.ceil(np.log2(max(n, 1))))
 
 
-def maybe_compact(table: DeviceTable) -> DeviceTable:
+def maybe_compact(table: DeviceTable,
+                  per_worker: Optional[np.ndarray] = None) -> DeviceTable:
     """Vector compaction when it at least halves capacity (§3.3.2): trims
     per-worker rows to pow2(max per-worker valid count). Shared by the
     mesh exchange paths and the driver's blocking operators (a sort over
-    ``max_groups`` padding costs more than this one metadata sync)."""
-    per_worker = np.asarray(table.validity.sum(axis=1))
+    ``max_groups`` padding costs more than this one metadata sync).
+    ``per_worker`` is each worker's valid-row count where the caller's
+    metadata phase already holds it on the host (no second sync)."""
+    if per_worker is None:
+        per_worker = np.asarray(table.validity.sum(axis=1))
     cap = _pow2(int(per_worker.max()) if per_worker.size else 1)
     if cap * 2 <= table.validity.shape[1]:
         return _compact_stacked(table, cap)
@@ -263,6 +267,56 @@ def _broadcast_fused(table: DeviceTable, num_workers: int,
     return DeviceTable(cols, valid, table.schema)
 
 
+def _constrain(tree, mesh: Mesh, axis: str):
+    spec = NamedSharding(mesh, P(axis))
+    return jax.tree.map(
+        lambda x: jax.lax.with_sharding_constraint(x, spec), tree)
+
+
+# The mesh programs take the mesh and its axis as static arguments, so a
+# compiled program is keyed on (mesh, axis, shapes) alone: every query's
+# protocol instance (the session's, or a scheduler clone) shares it.
+
+@_jit(static_argnums=(1, 2, 3, 4))
+def _exchange_data(staged: DeviceTable, mesh: Mesh, axis: str,
+                   num_workers: int, part_cap: int) -> DeviceTable:
+    """Data phase on the mesh: the worker-axis transpose of the staged
+    send buffers, which lowers to an all-to-all over ICI."""
+    staged = _constrain(staged, mesh, axis)
+
+    def swap(x):  # [W_src, W_dst, cap, ...] -> [W_dst, W_src*cap, ...]
+        x = jnp.swapaxes(x, 0, 1)
+        return x.reshape((num_workers, num_workers * part_cap) + x.shape[3:])
+
+    cols = {n: swap(a) for n, a in staged.columns.items()}
+    out = DeviceTable(cols, swap(staged.validity), staged.schema)
+    return _constrain(out, mesh, axis)
+
+
+@_jit(static_argnums=(1, 2, 3))
+def _broadcast_data(table: DeviceTable, mesh: Mesh, axis: str,
+                    num_workers: int) -> DeviceTable:
+    """Broadcast on the mesh: every worker receives all rows (lowers to an
+    all-gather over ICI)."""
+    table = _constrain(table, mesh, axis)
+    cap = table.validity.shape[1]
+
+    def bcast(x):  # [W, cap, ...] -> every worker sees all rows
+        flat = x.reshape((1, num_workers * cap) + x.shape[2:])
+        return jnp.broadcast_to(flat, (num_workers,) + flat.shape[1:])
+
+    cols = {n: bcast(a) for n, a in table.columns.items()}
+    out = DeviceTable(cols, bcast(table.validity), table.schema)
+    return _constrain(out, mesh, axis)
+
+
+def _counts_to_host(counts: jax.Array) -> np.ndarray:
+    """The metadata phase's blocking device-to-host copy of row counts,
+    under the span ``repro.exchange.counts``."""
+    with tracing.span("repro.exchange.counts"):
+        return np.asarray(counts)
+
+
 class ExchangeProtocol:
     """Contract for moving worker-stacked tables between workers; the two
     implementations below mirror the paper's UcxExchange (device-native)
@@ -332,33 +386,13 @@ class ICIExchange(ExchangeProtocol):
         """Fresh ICI protocol on the same mesh/axis, zeroed stats."""
         return type(self)(self.mesh, self.axis)
 
-    def _constrain(self, tree):
-        if self.mesh is None:
-            return tree
-        spec = NamedSharding(self.mesh, P(self.axis))
-        return jax.tree.map(
-            lambda x: jax.lax.with_sharding_constraint(x, spec), tree)
-
-    @_jit(static_argnums=(0, 2, 3))
-    def _exchange_data(self, staged: DeviceTable, num_workers: int,
-                       part_cap: int) -> DeviceTable:
-        staged = self._constrain(staged)
-
-        def swap(x):  # [W_src, W_dst, cap, ...] -> [W_dst, W_src*cap, ...]
-            x = jnp.swapaxes(x, 0, 1)           # lowers to all-to-all on ICI
-            return x.reshape((num_workers, num_workers * part_cap) + x.shape[3:])
-
-        cols = {n: swap(a) for n, a in staged.columns.items()}
-        out = DeviceTable(cols, swap(staged.validity), staged.schema)
-        return self._constrain(out)
-
     def repartition(self, table, key_names, num_workers):
         t0 = time.perf_counter()
         table = self._ensure_rows(table)
         key_names = tuple(key_names)
         # metadata phase (rendezvous handshake): size the receive buffers
         backend = kernel_ops.current_backend()
-        counts = np.asarray(
+        counts = _counts_to_host(
             _partition_counts(table, key_names, num_workers, backend))
         if backend == "pallas":
             kernel_ops.count_dispatch("partition")
@@ -369,14 +403,16 @@ class ICIExchange(ExchangeProtocol):
         else:
             # on-mesh: staged send buffers whose worker-axis transpose
             # lowers to the ICI all-to-all, then receive-side compaction
-            # (vector compaction, §3.3.2). Compaction preserves each row's
-            # source worker and keys, so the metadata counts above stay
-            # valid — no second metadata pass
-            table = maybe_compact(table)
+            # (vector compaction, §3.3.2). A source worker's row of the
+            # counts is its valid-row count, and compaction preserves each
+            # row's source worker and keys, so the metadata above sizes
+            # both — no second metadata pass
+            table = maybe_compact(table, counts.sum(axis=1))
             part_cap = self._choose_part_cap(counts)
             staged = _partition_layout_table(table, key_names, num_workers,
                                              part_cap)
-            out = self._exchange_data(staged, num_workers, part_cap)
+            out = _exchange_data(staged, self.mesh, self.axis, num_workers,
+                                 part_cap)
             if out_cap < out.validity.shape[1]:
                 out = _compact_stacked(out, out_cap)
         self.stats.rounds += 1
@@ -386,29 +422,18 @@ class ICIExchange(ExchangeProtocol):
         self.stats.seconds += time.perf_counter() - t0
         return out
 
-    @_jit(static_argnums=(0, 2))
-    def _broadcast_data(self, table: DeviceTable, num_workers: int):
-        table = self._constrain(table)
-        cap = table.validity.shape[1]
-
-        def bcast(x):  # [W, cap, ...] -> every worker sees all rows
-            flat = x.reshape((1, num_workers * cap) + x.shape[2:])
-            return jnp.broadcast_to(flat, (num_workers,) + flat.shape[1:])
-
-        cols = {n: bcast(a) for n, a in table.columns.items()}
-        out = DeviceTable(cols, bcast(table.validity), table.schema)
-        return self._constrain(out)
-
     def broadcast(self, table, num_workers):
         t0 = time.perf_counter()
         table = self._ensure_rows(table)
         # metadata phase: valid counts size the replica buffers, so dead
         # padding is compacted away before replication W-fold
-        rows = int(table.num_valid())
+        per_worker = _counts_to_host(table.validity.sum(axis=1))
+        rows = int(per_worker.sum())
         if self.mesh is None:
             out = _broadcast_fused(table, num_workers, _pow2(rows))
         else:
-            out = self._broadcast_data(maybe_compact(table), num_workers)
+            out = _broadcast_data(maybe_compact(table, per_worker),
+                                  self.mesh, self.axis, num_workers)
         self.stats.rounds += 1
         self.stats.rows_moved += rows * (num_workers - 1)
         self.stats.bytes_moved += rows * (num_workers - 1) * _row_bytes(table)

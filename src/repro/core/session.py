@@ -9,6 +9,7 @@ submission, ExecutionContext -> worker fleet config.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import math
 import threading
 from typing import Dict, Iterator, Optional
@@ -21,6 +22,8 @@ from .exchange import ExchangeProtocol, ICIExchange
 from .plan import PlanNode
 from .streaming import HostMorsel, MorselPrefetcher, ScanStats, morsel_to_device
 from .table import DeviceTable
+
+_log = logging.getLogger(__name__)
 
 
 class TableSource:
@@ -101,8 +104,24 @@ class TableSource:
         raise NotImplementedError
 
 
+def _worker_view(arr: np.ndarray, lo: int, w: int, per_worker: int,
+                 cap: int) -> np.ndarray:
+    """Rows ``[k * per_worker + lo, k * per_worker + lo + cap)`` of ``arr``
+    for each worker ``k < w``, as a read-only ``[w, cap, ...]`` view."""
+    s0 = arr.strides[0]
+    return np.lib.stride_tricks.as_strided(
+        arr[lo:], shape=(w, cap) + arr.shape[1:],
+        strides=(per_worker * s0,) + arr.strides, writeable=False)
+
+
 class InMemoryTable(TableSource):
-    """Numpy-backed table; rows are range-partitioned across workers."""
+    """Numpy-backed table; rows are range-partitioned across workers.
+
+    Each worker's rows of a morsel are one contiguous run of every column,
+    so a morsel is a read-only strided view of the table and a scan makes
+    no host copy. Where the last worker runs out of rows, its view reads
+    zero rows kept past the column's end (invalid); that padded copy of a
+    scanned column is made once, on the first such scan, and kept."""
 
     def __init__(self, name: str, data: Dict[str, np.ndarray], schema: dict,
                  unique_keys: tuple = ()):
@@ -112,9 +131,20 @@ class InMemoryTable(TableSource):
         self.schema = dict(schema)
         self.unique_keys = tuple(tuple(u) for u in unique_keys)
         self._n = len(next(iter(self.data.values()))) if self.data else 0
+        self._padded: Dict[str, np.ndarray] = {}
 
     def num_rows(self) -> int:
         return self._n
+
+    def _with_tail(self, name: str, rows: int) -> np.ndarray:
+        """Column ``name`` followed by at least ``rows`` zero rows."""
+        buf = self._padded.get(name)
+        if buf is None or len(buf) - self._n < rows:
+            col = self.data[name]
+            buf = np.zeros((self._n + rows,) + col.shape[1:], col.dtype)
+            buf[:self._n] = col
+            self._padded[name] = buf
+        return buf
 
     def _host_morsels(self, num_workers: int, columns, batch_rows: int,
                       filter_expr=None,
@@ -125,26 +155,23 @@ class InMemoryTable(TableSource):
         per_worker = math.ceil(self._n / w) if self._n else 1
         n_batches = max(1, math.ceil(per_worker / batch_rows))
         schema = {c: self.schema[c] for c in cols}
+        first = per_worker * np.arange(w)
         for b in range(n_batches):
             lo = b * batch_rows
-            hi = min(lo + batch_rows, per_worker)
-            cap = hi - lo
-            stacked_cols, stacked_valid = {}, np.zeros((w, cap), dtype=bool)
+            cap = min(lo + batch_rows, per_worker) - lo
+            # rows the last worker's view reads past the table's end
+            past = first[-1] + lo + cap - self._n
+            valid = np.zeros((w, cap), dtype=bool)
+            for k, r in enumerate(np.clip(self._n - first - lo, 0, cap)):
+                valid[k, :r] = True
+            stacked = {}
             for name in cols:
-                dt_ = self.schema[name]
-                arr = self.data[name]
-                shape = (w, cap, dt_.width) if dt_.name == "bytes" else (w, cap)
-                buf = np.zeros(shape, dtype=dt_.np_dtype())
-                for wk in range(w):
-                    base = wk * per_worker
-                    s, e = base + lo, min(base + hi, self._n)
-                    if e > s:
-                        buf[wk, : e - s] = arr[s:e]
-                        stacked_valid[wk, : e - s] = True
-                stacked_cols[name] = buf
+                arr = (self.data[name] if past <= 0
+                       else self._with_tail(name, past))
+                stacked[name] = _worker_view(arr, lo, w, per_worker, cap)
                 if stats is not None:
-                    stats.bytes_read += buf.nbytes
-            yield HostMorsel(stacked_cols, stacked_valid, schema)
+                    stats.bytes_read += stacked[name].nbytes
+            yield HostMorsel(stacked, valid, schema)
 
 
 class Catalog:
@@ -261,7 +288,10 @@ class Session:
     exchange: Optional[ExchangeProtocol] = None
     batch_rows: int = 8192
     host_only_ops: frozenset = frozenset()
-    mesh: Optional[object] = None          # Mesh with a 'workers' axis
+    # Mesh with a 'workers' axis. None places one worker per device where
+    # the process has a device for every worker (``worker_mesh``), else
+    # the workers share one device
+    mesh: Optional[object] = None
     # morsel-driven scan pipeline: async storage->device prefetch with a
     # bounded queue of `prefetch_depth` in-flight morsels (False = the
     # synchronous materialize-then-run baseline)
@@ -309,6 +339,31 @@ class Session:
                 fb = self.feedback
         return fb if fb is not None and fb is not False else None
 
+    def worker_mesh(self):
+        """The mesh this session's queries run on: ``mesh`` if given;
+        else, for ``num_workers > 1`` in a process with at least that many
+        devices, a 1-D ``workers`` mesh of one worker per device, built
+        once per session; else ``None``: the workers then share one
+        device, and the session logs a warning once."""
+        if self.mesh is not None or self.num_workers <= 1:
+            return self.mesh
+        if "_worker_mesh" not in self.__dict__:
+            with Session._scheduler_lock:
+                if "_worker_mesh" not in self.__dict__:
+                    self._worker_mesh = self._build_worker_mesh()
+        return self._worker_mesh
+
+    def _build_worker_mesh(self):
+        import jax
+
+        from ..launch.mesh import make_engine_mesh
+        n = jax.device_count()
+        if n >= self.num_workers:
+            return make_engine_mesh(self.num_workers)
+        _log.warning("Session(num_workers=%d) found %d device(s): its "
+                     "workers share one device", self.num_workers, n)
+        return None
+
     def context(self) -> ExecutionContext:
         """Snapshot this session's execution config for one Driver run
         (each context gets its own per-query ``SpillManager``)."""
@@ -318,13 +373,14 @@ class Session:
             spill = SpillManager(self.device_budget, self.host_budget,
                                  spill_dir=self.spill_dir,
                                  disk_ceiling=self.disk_ceiling)
+        mesh = self.worker_mesh()
         return ExecutionContext(
             catalog=self.catalog,
             num_workers=self.num_workers,
-            exchange=self.exchange or ICIExchange(mesh=self.mesh),
+            exchange=self.exchange or ICIExchange(mesh=mesh),
             batch_rows=self.batch_rows,
             host_only_ops=self.host_only_ops,
-            mesh=self.mesh,
+            mesh=mesh,
             streaming=self.streaming,
             prefetch_depth=self.prefetch_depth,
             kernel_backend=self.kernel_backend,
@@ -517,7 +573,8 @@ class Session:
         chunks skipped by zone maps, prefetch-overlap fraction -- plus
         each operator's host dispatch seconds (``op_host_seconds``: host
         time of its own calls, not device time), the streaming
-        aggregation's partials, merges and rows merged (``agg``),
+        aggregation's partials, merges and rows merged (``agg``), the
+        device programs traced per family (``traces``; none once warm),
         per-fragment exchange stats (rows/bytes moved,
         host-staged bytes per Repartition/Broadcast), the per-operator
         memory-footprint breakdown, and -- when a ``device_budget`` is set
@@ -555,6 +612,9 @@ class Session:
         if agg.get("partials"):
             lines.append("agg: " + " ".join(f"{k}={v}"
                                             for k, v in agg.items()))
+        traces = stats.get("traces") or {}
+        lines.append("traces: " + (" ".join(
+            f"{k}={v}" for k, v in sorted(traces.items())) or "none"))
         for frag, ex in stats.get("exchanges", {}).items():
             lines.append(
                 f"exchange {frag} [{stats.get('exchange_protocol')}]: "

@@ -10,6 +10,8 @@
 * ``program(family, fn)`` names a device program after the operator family
   that issues it: jitted, its XLA module reads ``jit_<family>__<fn>``, and
   its HLO op metadata carries ``<family>/`` through ``jax.named_scope``.
+  Its body runs only while JAX traces the program, so it also counts
+  traces per family into the ``collect_traces`` scope a query runs under.
 
 Take a trace of a live session with ``jax.profiler.trace(log_dir)`` around
 the queries of interest; ``PERF.md`` lists every span name.
@@ -20,7 +22,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import functools
-from typing import Iterator, Optional
+from typing import Dict, Iterator, Optional
 
 import jax
 from jax.profiler import TraceAnnotation
@@ -34,6 +36,8 @@ FAMILIES = ("scan", "agg", "join", "sort", "exchange", "batch")
 
 _QUERY_ID: contextvars.ContextVar[Optional[int]] = contextvars.ContextVar(
     "repro_query_id", default=None)
+_TRACES: contextvars.ContextVar[Optional[Dict[str, int]]] = \
+    contextvars.ContextVar("repro_traces", default=None)
 _OFF = contextlib.nullcontext()
 
 
@@ -59,15 +63,31 @@ def query(query_id: int) -> Iterator[None]:
         _QUERY_ID.reset(token)
 
 
+@contextlib.contextmanager
+def collect_traces(counts: Dict[str, int]) -> Iterator[None]:
+    """Count the traces of ``program``s in this context into ``counts``
+    (family -> traces); the driver wraps each query with this and surfaces
+    the dict as ``executor_stats()['traces']``. A warm query reads none."""
+    token = _TRACES.set(counts)
+    try:
+        yield
+    finally:
+        _TRACES.reset(token)
+
+
 def program(family: str, fn):
     """``fn`` renamed ``<family>__<name>``, its body under
-    ``jax.named_scope(family)``."""
+    ``jax.named_scope(family)``; each trace of it counts once for
+    ``family`` in the active ``collect_traces`` scope."""
     if family not in FAMILIES:
         raise ValueError(f"unknown program family {family!r}; "
                          f"known: {FAMILIES}")
 
     @functools.wraps(fn)
     def named(*args, **kwargs):
+        counts = _TRACES.get()
+        if counts is not None:
+            counts[family] = counts.get(family, 0) + 1
         with jax.named_scope(family):
             return fn(*args, **kwargs)
 

@@ -78,6 +78,44 @@ def test_paged_source_roundtrip_matches_inmemory(tmp_path):
                                       np.sort(data[c], axis=0))
 
 
+@pytest.mark.parametrize("n,workers,batch", [
+    (1000, 1, 256), (1000, 4, 100), (1000, 4, 250), (1003, 4, 100),
+    (999, 3, 1000), (7, 8, 4), (0, 4, 16)])
+def test_in_memory_morsels_are_views(n, workers, batch):
+    """``InMemoryTable`` morsels equal a zero-padded copy of each worker's
+    rows, and are read-only views: a batch in which every worker has all
+    its rows views the table's own column, one that runs past the end
+    views a padded copy made once for every later scan."""
+    import math
+
+    from repro.core.session import InMemoryTable
+
+    data = _data(n)
+    src = InMemoryTable("t", data, SCHEMA)
+    per_worker = math.ceil(n / workers) if n else 1
+    morsels = list(src._host_morsels(workers, None, batch))
+    again = list(src._host_morsels(workers, None, batch))
+    assert len(morsels) == max(1, math.ceil(per_worker / batch))
+    for b, (m, m2) in enumerate(zip(morsels, again)):
+        lo = b * batch
+        cap = min(lo + batch, per_worker) - lo
+        want_valid = np.zeros((workers, cap), dtype=bool)
+        full = True
+        for c, col in data.items():
+            want = np.zeros((workers, cap) + col.shape[1:], col.dtype)
+            for k in range(workers):
+                rows = col[k * per_worker + lo:
+                           min(k * per_worker + lo + cap, n)]
+                want[k, :len(rows)] = rows
+                want_valid[k, :len(rows)] = True
+                full &= len(rows) == cap
+            np.testing.assert_array_equal(m.columns[c], want)
+            assert not m.columns[c].flags.writeable
+            assert np.shares_memory(m.columns[c], src.data[c]) == full
+            assert np.shares_memory(m.columns[c], m2.columns[c])
+        np.testing.assert_array_equal(m.validity, want_valid)
+
+
 # -- zone-map skipping: identical results with skipping on/off --------------
 
 @pytest.mark.parametrize("backend", ["colchunk", "paged"])

@@ -5,8 +5,11 @@ Nothing executes: each kernel is lowered with shapes placed on one chip of
 a described (not attached) ``v5e:2x2`` topology and compiled by the TPU's
 own compiler, which refuses what interpret mode accepts (per-lane VMEM
 gathers, int32 MXU matmuls, stacked i1 vectors, scoped-VMEM overflow).
-Each compile must yield a Mosaic ``tpu_custom_call``. Where no v5e topology
-can be described, the fixture skips these tests.
+Each compile must yield a Mosaic ``tpu_custom_call``. The exchange's mesh
+programs are compiled the same way for all four chips of the topology, at
+the sizes TPC-H Q3 gives them at SF 1, and must lower to their ICI
+collectives. Where no v5e topology can be described, the fixture skips
+these tests.
 """
 
 from __future__ import annotations
@@ -20,7 +23,7 @@ import pytest
 from jax.experimental.compilation_cache import compilation_cache
 
 from repro.core import dtypes as dt
-from repro.core import fused
+from repro.core import exchange, fused
 from repro.core.batch import ParamRef, apply_batched_stages
 from repro.core.expr import col
 from repro.core.table import DeviceTable
@@ -39,12 +42,10 @@ I32, F32 = jnp.int32, jnp.float32
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One chip of a described v5e:2x2 topology, with the persistent
-    compile cache off (an entry compiled for a described chip cannot be
-    read back here)."""
+def topo():
+    """A described v5e:2x2 topology, with the persistent compile cache off
+    (an entry compiled for a described chip cannot be read back here)."""
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     with pytest.MonkeyPatch.context() as mp:
         if "TPU_LOG_DIR" not in os.environ:
@@ -58,10 +59,28 @@ def one_chip():
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         try:
-            yield SingleDeviceSharding(topo.devices[0])
+            yield topo
         finally:
             jax.config.update("jax_enable_compilation_cache", was)
             compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One chip of the described topology."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """The engine's 1-D ``workers`` mesh over the topology's four chips."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+
+    return Mesh(np.asarray(topo.devices).reshape(4), ("workers",),
+                axis_types=(AxisType.Auto,))
 
 
 def _q6_table(shape):
@@ -160,3 +179,35 @@ def test_kernel_compiles_for_v5e(one_chip, name):
             for spec in specs]
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo, name
+
+
+# Q3 at SF 1 on four workers: the largest repartition sends lineitem's
+# (l_orderkey, revenue) rows in [W_src, W_dst, 2^19] buffers; the largest
+# broadcast replicates a 2^16-row build side
+MESH_PROGRAMS = {
+    "exchange_data": ("all-to-all", lambda mesh, sh: exchange._exchange_data
+                      .lower(_mesh_table(sh, (4, 4, 1 << 19)), mesh,
+                             "workers", 4, 1 << 19)),
+    "broadcast_data": ("all-gather", lambda mesh, sh: exchange
+                       ._broadcast_data.lower(_mesh_table(sh, (4, 1 << 16)),
+                                              mesh, "workers", 4)),
+}
+
+
+def _mesh_table(sharding, shape):
+    def leaf(d):
+        return jax.ShapeDtypeStruct(shape, d, sharding=sharding)
+
+    schema = {"l_orderkey": dt.INT32, "revenue": dt.FLOAT32}
+    return DeviceTable({"l_orderkey": leaf(I32), "revenue": leaf(F32)},
+                       leaf(jnp.bool_), schema)
+
+
+@pytest.mark.parametrize("name", sorted(MESH_PROGRAMS))
+def test_mesh_exchange_compiles_for_four_v5e_chips(four_chips, name):
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    collective, lower = MESH_PROGRAMS[name]
+    sharding = NamedSharding(four_chips, PartitionSpec("workers"))
+    hlo = lower(four_chips, sharding).compile().as_text()
+    assert collective in hlo, name

@@ -123,6 +123,36 @@ def test_agg_counters_per_query(keys, merges):
         qb.explain(analyze=True)
 
 
+def test_program_counts_each_trace_per_family():
+    prog = jax.jit(tracing.program("join", lambda x: x + 1))
+    counts = {}
+    with tracing.collect_traces(counts):
+        prog(jnp.zeros(3))
+        prog(jnp.ones(3))                      # same shape: no trace
+        prog(jnp.zeros(5))                     # a new shape traces again
+    prog(jnp.zeros(7))                         # outside the scope
+    assert counts == {"join": 2}
+
+
+def test_warm_query_traces_nothing():
+    sess = Session(dbgen.load_catalog(sf=0.002), batch_rows=2048,
+                   scheduler_config=SchedulerConfig(cache_results=False))
+    text = ("SELECT l_returnflag, sum(l_quantity) AS q FROM lineitem "
+            "WHERE l_discount < 0.031 GROUP BY l_returnflag")
+    handles = []
+    for _ in range(2):
+        handles.append(sess.sql(text).submit())
+        handles[-1].result()
+    sess.scheduler().close()
+    cold, warm = handles
+    assert cold.executor_stats["traces"].get("agg", 0) > 0
+    assert warm.executor_stats["traces"] == {}
+    shown = sess.sql(text).explain(analyze=True)
+    traces = sess.executor_stats()["traces"]
+    assert "traces: " + " ".join(
+        f"{k}={v}" for k, v in sorted(traces.items())) in shown
+
+
 class _Sleeper(ops.Operator):
     """Passes batches through; each own call sleeps ``delay`` seconds."""
 
