@@ -161,6 +161,7 @@ def empty_executor_stats() -> Dict[str, object]:
         "kernel_backend": "",
         "kernel_dispatch": {},
         "agg": {},
+        "join": {},
         "traces": {},
         "spill": {},
         "spill_staged_exchanges": 0,
@@ -190,6 +191,9 @@ class Driver:
         self.kernel_dispatch: Dict[str, int] = {}
         # per-query HashAggregation counters: partials, merges, merge_rows
         self.agg_stats: Dict[str, int] = dict.fromkeys(ops.AGG_COUNTERS, 0)
+        # per-query jnp-path HashJoin counters: probes by lookup, and the
+        # largest direct-address index built
+        self.join_stats: Dict[str, int] = dict.fromkeys(ops.JOIN_COUNTERS, 0)
         # per-query traces of device programs, per family (a warm query
         # traces nothing)
         self.trace_counts: Dict[str, int] = {}
@@ -209,8 +213,8 @@ class Driver:
     def executor_stats(self) -> Dict[str, object]:
         """Per-query executor stats: scan counters, operator host times,
         kernel backend + dispatch counts, aggregation merge counters
-        (``agg``), program traces per family (``traces``), per-fragment
-        exchange counters
+        (``agg``), join probes by lookup (``join``), program traces per
+        family (``traces``), per-fragment exchange counters
         (rows/bytes moved, host staging), per-tier spill counters, and the
         feedback-store summary. Same key shape as
         ``empty_executor_stats()``."""
@@ -223,6 +227,7 @@ class Driver:
             "kernel_backend": self.ctx.kernel_backend,
             "kernel_dispatch": dict(self.kernel_dispatch),
             "agg": dict(self.agg_stats),
+            "join": dict(self.join_stats),
             "traces": dict(self.trace_counts),
             "spill": (self.ctx.spill.stats.summary()
                       if self.ctx.spill is not None else {}),
@@ -232,13 +237,14 @@ class Driver:
         }
 
     def _kernel_scope(self):
-        """Backend + dispatch-, merge- and trace-accounting scope one query
-        runs under."""
+        """Backend + dispatch-, merge-, join- and trace-accounting scope
+        one query runs under."""
         scope = contextlib.ExitStack()
         scope.enter_context(kernel_ops.use_backend(self.ctx.kernel_backend))
         scope.enter_context(
             kernel_ops.collect_dispatches(self.kernel_dispatch))
         scope.enter_context(ops.collect_agg_stats(self.agg_stats))
+        scope.enter_context(ops.collect_join_stats(self.join_stats))
         scope.enter_context(tracing.collect_traces(self.trace_counts))
         return scope
 

@@ -343,20 +343,20 @@ def _finalize_avg(table: DeviceTable, user_specs):
     return DeviceTable(cols, table.validity, schema)
 
 
-# per-query aggregation counters (``executor_stats()["agg"]``): the driver
-# opens a scope around each query, as it does for kernel dispatches, and
-# HashAggregation reports into it from static shapes (no device sync)
+# per-query operator counters (``executor_stats()["agg"]``, ``["join"]``):
+# the driver opens a scope around each query, as it does for kernel
+# dispatches, and the operators report into it from host-side state
 AGG_COUNTERS = ("partials", "merges", "merge_rows")
-_agg_tls = threading.local()
+JOIN_COUNTERS = ("dense_probes", "sorted_probes", "index_slots")
+_stats_tls = threading.local()
 
 
 @contextlib.contextmanager
-def collect_agg_stats(counts: Dict[str, int]) -> Iterator[None]:
-    """Add HashAggregation's counters (``AGG_COUNTERS``) into ``counts``
-    for the duration of the scope, on this thread."""
-    stack = getattr(_agg_tls, "stack", None)
+def _collect(group: str, counts: Dict[str, int]) -> Iterator[None]:
+    stack = getattr(_stats_tls, group, None)
     if stack is None:
-        stack = _agg_tls.stack = []
+        stack = []
+        setattr(_stats_tls, group, stack)
     stack.append(counts)
     try:
         yield
@@ -364,9 +364,22 @@ def collect_agg_stats(counts: Dict[str, int]) -> Iterator[None]:
         stack.pop()
 
 
-def _count_agg(name: str, n: int = 1) -> None:
-    for counts in getattr(_agg_tls, "stack", ()):
-        counts[name] = counts.get(name, 0) + n
+def collect_agg_stats(counts: Dict[str, int]):
+    """Add HashAggregation's counters (``AGG_COUNTERS``) into ``counts``
+    for the duration of the scope, on this thread."""
+    return _collect("agg", counts)
+
+
+def collect_join_stats(counts: Dict[str, int]):
+    """Add the jnp-path HashJoin's counters (``JOIN_COUNTERS``) into
+    ``counts`` for the duration of the scope, on this thread: probe calls
+    by lookup (index or binary search) and the largest index built."""
+    return _collect("join", counts)
+
+
+def _count(group: str, name: str, n: int = 1, combine=int.__add__) -> None:
+    for counts in getattr(_stats_tls, group, ()):
+        counts[name] = combine(counts.get(name, 0), n)
 
 
 def _rows(table: DeviceTable) -> int:
@@ -449,15 +462,15 @@ class HashAggregation(Operator):
             return tables[0]
         merged = concat_tables(tables)
         rows = _rows(merged)
-        _count_agg("merges")
-        _count_agg("merge_rows", rows)
+        _count("agg", "merges")
+        _count("agg", "merge_rows", rows)
         return _aggregate(merged, self.group_keys, merge_specs(self.specs),
                           min(self.max_groups, rows))
 
     def add_input(self, batch):
         self._held.append(_aggregate(batch, self.group_keys, self.specs,
                                      min(self.max_groups, _rows(batch))))
-        _count_agg("partials")
+        _count("agg", "partials")
         if sum(_rows(t) for t in self._held) < self._merge_at:
             return []
         acc = self._merge(self._held)
@@ -521,10 +534,43 @@ MAX_HASH_TABLE_SLOTS = 1 << 18
 EMPTY_KEY = -1
 
 
+# the sorted build's direct-address index may take up to this many slots
+# per build row slot (64 B of int32 counts per row): past it the probe
+# keeps the binary search
+MAX_INDEX_SLOTS_PER_ROW = 16
+
+
 @table_op(family="join")
-def _build_join_table(build: DeviceTable, build_keys):
+def _build_join_table(build: DeviceTable, build_keys, slots: int = 0):
     key, _ = rel.join_key([build.columns[k] for k in build_keys])
-    return rel.join_build(key, build.validity)
+    return rel.join_build(key, build.validity, slots)
+
+
+@table_op(family="join")
+def _join_key_range(build: DeviceTable, build_keys):
+    """(min, max) of the valid build keys; (INT32_MAX, INT32_MIN) if none."""
+    key, _ = rel.join_key([build.columns[k] for k in build_keys])
+    info = jnp.iinfo(jnp.int32)
+    return jnp.stack([jnp.min(jnp.where(build.validity, key, info.max)),
+                      jnp.max(jnp.where(build.validity, key, info.min))])
+
+
+def _index_slots(build: DeviceTable, build_keys) -> int:
+    """Slots S of the sorted build's direct-address index, or 0 for the
+    binary search. S is the power of two at or above ``max - min + 2`` of
+    the valid keys (over all workers of a stacked build, so every worker
+    shares one static shape), taken while it stays within
+    ``MAX_INDEX_SLOTS_PER_ROW`` times the build's per-worker row slots.
+    A valid INT32_MAX key keeps the binary search: it would share its
+    sorted run with the padded invalid rows, which the index does not
+    count."""
+    rng = np.asarray(_join_key_range(build, build_keys)).reshape(-1, 2)
+    lo, hi = int(rng[:, 0].min()), int(rng[:, 1].max())
+    if hi < lo or hi == np.iinfo(np.int32).max:
+        return 0
+    slots = _pow2(hi - lo + 2)
+    cap = _pow2(build.validity.shape[-1])
+    return slots if slots <= MAX_INDEX_SLOTS_PER_ROW * cap else 0
 
 
 def _join_probe_key(table: DeviceTable, key_names, pack):
@@ -753,8 +799,12 @@ class HashJoin(Operator):
     TPU adaptation of cuDF's hash join, with a per-session kernel backend
     (``kernels.ops.current_backend()``, sampled at ``seal_build``):
 
-    * 'jnp'    -- the build side becomes a sorted key array probed with
-                  searchsorted (doubles as the oracle);
+    * 'jnp'    -- the build side becomes a sorted key array (doubles as
+                  the oracle). An exact key whose valid range spans at
+                  most ``MAX_INDEX_SLOTS_PER_ROW`` slots per build row
+                  also gets a direct-address prefix count over that
+                  range, read once per probe key; other builds are
+                  probed with searchsorted;
     * 'pallas' -- exact int-like keys build an open-addressing table
                   (``kernels.build_table``, power-of-two slots sized 2x
                   the planner's ``build_rows`` bound). Composite int-like
@@ -794,6 +844,7 @@ class HashJoin(Operator):
         self._hash_state = None          # (build, table_keys, table_vals)
         self._max_probes = 0
         self._exact = True
+        self._index_slots = 0            # jnp path: direct-address index
         self._pack = None                # composite-key windows, or None
         self._multi = False              # expansion probe (hash_probe_multi)
 
@@ -851,7 +902,12 @@ class HashJoin(Operator):
             # once per sealed build so the adaptive suite can assert warm
             # re-plans with tighter bounds shrink it.
             kernel_ops.count_dispatch("fallback_probe")
-        bt = _build_join_table(build, self.build_keys)
+        # exact keys over a dense enough range take the direct-address
+        # index (one host read of the key range); hashed keys never do
+        self._index_slots = (_index_slots(build, self.build_keys)
+                             if self._exact else 0)
+        _count("join", "index_slots", self._index_slots, max)
+        bt = _build_join_table(build, self.build_keys, self._index_slots)
         self._state = (build, bt)
 
     def add_input(self, batch):
@@ -870,6 +926,8 @@ class HashJoin(Operator):
                                        self.join_type, self._max_probes,
                                        self._pack)]
         assert self._state is not None, "probe before build sealed"
+        _count("join", "dense_probes" if self._index_slots
+               else "sorted_probes")
         out = _probe_join(batch, self._state, self.probe_keys, self.build_keys,
                           self.build_payload, self.join_type, self.max_matches,
                           self._exact)

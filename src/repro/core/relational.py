@@ -12,10 +12,11 @@ reused by operators, kernels' ref.py, and the exchange partitioner.
 
 from __future__ import annotations
 
-from typing import List, NamedTuple, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ..kernels import ops as kernel_ops
 
@@ -286,22 +287,66 @@ def _extreme(dtype, sign):
 
 
 # ---------------------------------------------------------------------------
-# joins (sort + searchsorted; the Pallas kernel gives the hash-table variant)
+# joins (sort + searchsorted, or a direct-address index over the key
+# range; the Pallas kernel gives the hash-table variant)
 # ---------------------------------------------------------------------------
 
 class BuildTable(NamedTuple):
-    """Sorted join build side (keys, permutation, original validity)."""
+    """Sorted join build side (keys, permutation, original validity), and
+    optionally a direct-address index over the valid keys' range."""
 
     sorted_keys: jax.Array   # int32[B], invalid rows pushed to +inf end
     perm: jax.Array          # int32[B] permutation into original build rows
     validity: jax.Array      # original build validity
+    # int32[S+1]: index[i] counts the valid keys below lo + i, where
+    # lo = sorted_keys[0] is the smallest (None: binary search)
+    index: Optional[jax.Array] = None
 
 
-def join_build(keys: jax.Array, validity: jax.Array) -> BuildTable:
-    """Sort build keys (invalid rows to the end) for searchsorted probes."""
+def _key_offset(keys: jax.Array, lo: jax.Array, slots: int) -> jax.Array:
+    """``keys - lo`` clamped to [0, slots]; the difference is taken in
+    uint32, where it is exact for every ``keys >= lo``, so no int32 key
+    wraps around."""
+    u32 = lambda x: lax.bitcast_convert_type(x, jnp.uint32)  # noqa: E731
+    d = jnp.minimum(u32(keys) - u32(lo), jnp.uint32(slots))
+    return jnp.where(keys < lo, 0, d.astype(jnp.int32))
+
+
+def join_build(keys: jax.Array, validity: jax.Array,
+               slots: int = 0) -> BuildTable:
+    """Sort build keys (invalid rows to the end) for the probes.
+
+    With ``slots`` S > 0 the table also carries a prefix count of the
+    valid keys over ``[lo, lo + S)``, ``lo`` their minimum: the caller
+    guarantees ``max - lo + 2 <= S``, so the last slot counts no key."""
     k = jnp.where(validity, keys, INT32_MAX)
     perm = jnp.argsort(k, stable=True).astype(jnp.int32)
-    return BuildTable(jnp.take(k, perm), perm, validity)
+    sorted_keys = jnp.take(k, perm)
+    index = None
+    if slots:
+        off = _key_offset(keys, sorted_keys[0], slots)
+        off = jnp.where(validity, off, slots)
+        hist = jnp.zeros((slots,), jnp.int32).at[off].add(1, mode="drop")
+        index = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                 jnp.cumsum(hist, dtype=jnp.int32)])
+    return BuildTable(sorted_keys, perm, validity, index)
+
+
+def _match_range(bt: BuildTable, probe_keys: jax.Array):
+    """Per probe key, [start, end) of its equal keys in ``sorted_keys``:
+    two reads of the index where the table has one, else two binary
+    searches. The two agree for every key below INT32_MAX; at INT32_MAX
+    the index does not count the invalid rows padded to the end."""
+    if bt.index is None:
+        start = jnp.searchsorted(bt.sorted_keys, probe_keys, side="left")
+        end = jnp.searchsorted(bt.sorted_keys, probe_keys, side="right")
+        return start.astype(jnp.int32), end.astype(jnp.int32)
+    slots = bt.index.shape[0] - 1
+    lo = bt.sorted_keys[0]
+    i = _key_offset(probe_keys, lo, slots)
+    nxt = jnp.minimum(i + (probe_keys >= lo), slots)
+    return (jnp.take(bt.index, i, mode="clip"),
+            jnp.take(bt.index, nxt, mode="clip"))
 
 
 class ProbeResult(NamedTuple):
@@ -318,8 +363,7 @@ def join_probe(bt: BuildTable, probe_keys: jax.Array, probe_valid: jax.Array,
                max_matches: int) -> ProbeResult:
     """Expansion probe with static output capacity P * max_matches."""
     p = probe_keys.shape[0]
-    start = jnp.searchsorted(bt.sorted_keys, probe_keys, side="left").astype(jnp.int32)
-    end = jnp.searchsorted(bt.sorted_keys, probe_keys, side="right").astype(jnp.int32)
+    start, end = _match_range(bt, probe_keys)
     count = jnp.where(probe_valid, end - start, 0)
     m = max_matches
     j = jnp.arange(p * m, dtype=jnp.int32)
@@ -335,8 +379,7 @@ def join_probe(bt: BuildTable, probe_keys: jax.Array, probe_valid: jax.Array,
 def semi_mask(bt: BuildTable, probe_keys: jax.Array,
               probe_valid: jax.Array) -> jax.Array:
     """probe rows with >=1 match (EXISTS). Anti = probe_valid & ~semi."""
-    start = jnp.searchsorted(bt.sorted_keys, probe_keys, side="left")
-    end = jnp.searchsorted(bt.sorted_keys, probe_keys, side="right")
+    start, end = _match_range(bt, probe_keys)
     return probe_valid & (end > start)
 
 

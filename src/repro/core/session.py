@@ -574,6 +574,7 @@ class Session:
         each operator's host dispatch seconds (``op_host_seconds``: host
         time of its own calls, not device time), the streaming
         aggregation's partials, merges and rows merged (``agg``), the
+        jnp join's probes by lookup and largest index (``join``), the
         device programs traced per family (``traces``; none once warm),
         per-fragment exchange stats (rows/bytes moved,
         host-staged bytes per Repartition/Broadcast), the per-operator
@@ -612,6 +613,10 @@ class Session:
         if agg.get("partials"):
             lines.append("agg: " + " ".join(f"{k}={v}"
                                             for k, v in agg.items()))
+        join = stats.get("join") or {}
+        if join.get("dense_probes") or join.get("sorted_probes"):
+            lines.append("join: " + " ".join(f"{k}={v}"
+                                             for k, v in join.items()))
         traces = stats.get("traces") or {}
         lines.append("traces: " + (" ".join(
             f"{k}={v}" for k, v in sorted(traces.items())) or "none"))
