@@ -1,6 +1,6 @@
 """Pure-JAX relational algorithms on static-shape columnar batches.
 
-These are the TPU adaptations of cuDF's SIMT primitives (DESIGN.md §2):
+These are the TPU adaptations of cuDF's SIMT primitives:
 dynamic hash tables become sort-based segmenting / open-addressing in fixed
 buffers, dynamic output sizes become static-capacity expansions with planner
 hints. The Pallas kernels in repro.kernels accelerate the hot spots; these

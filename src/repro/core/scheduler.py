@@ -44,7 +44,7 @@ turns the one-query-at-a-time ``Session`` into a serving engine:
 Entry points live on ``Session``: ``submit()`` returns a ``QueryHandle``
 future, ``gather()`` awaits many, ``run()`` is the synchronous wrapper.
 ``examples/serve_queries.py`` demonstrates N concurrent TPC-H clients;
-``benchmarks/bench_concurrency.py`` measures throughput and latency.
+the benchmark cells under ``bench/`` measure throughput on the chip.
 """
 
 from __future__ import annotations

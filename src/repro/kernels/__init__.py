@@ -11,8 +11,7 @@ physical primitive of the query engine and ships with a pure-jnp oracle in
 * ``block_prefix_sum``  -- two-level scan producing stream-compaction
                            addresses (``DeviceTable.compact``);
 * ``radix_histogram``   -- per-partition row counts (the exchange's
-                           metadata phase);
-* ``flash_attention``   -- blocked attention (model-side workloads).
+                           metadata phase).
 
 ``ops`` carries the jit'd public wrappers plus the engine's backend switch
 (``use_pallas`` / ``use_backend``, see ``core`` for how the driver selects
@@ -26,7 +25,6 @@ from .ops import (
     build_table,
     current_backend,
     default_backend,
-    flash_attention,
     hash_probe,
     radix_histogram,
     segmented_sum,
@@ -37,7 +35,7 @@ from .ops import (
 
 __all__ = [
     "ops", "ref", "BACKENDS",
-    "block_prefix_sum", "build_table", "flash_attention", "hash_probe",
+    "block_prefix_sum", "build_table", "hash_probe",
     "radix_histogram", "segmented_sum",
     "current_backend", "default_backend", "set_default_backend",
     "use_backend", "use_pallas",

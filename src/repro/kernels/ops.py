@@ -32,7 +32,6 @@ import jax
 
 from . import ref  # noqa: F401  (oracles re-exported for convenience)
 from .block_prefix_sum import block_prefix_sum as _bps
-from .flash_attention import flash_attention as _flash
 from .hash_probe import (build_table as _build, hash_probe as _probe,
                          hash_probe_multi as _probe_multi)
 from .radix_histogram import radix_histogram as _hist
@@ -197,12 +196,6 @@ def on_tpu() -> bool:
 
 def _interp() -> bool:
     return not on_tpu()
-
-
-def flash_attention(q, k, v, causal=True, **kw):
-    """Blocked flash attention: [B, H, S, D] -> [B, H, S, D]."""
-    _mark("attention")
-    return _flash(q, k, v, causal=causal, interpret=_interp(), **kw)
 
 
 def segmented_sum(gids, values, num_groups, **kw):

@@ -10,18 +10,6 @@ import jax
 import jax.numpy as jnp
 
 
-def flash_attention(q, k, v, causal: bool = True):
-    """q,k,v: [B, H, S, D] -> [B, H, S, D] full-softmax attention."""
-    s = q.shape[2]
-    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32)
-    scores = scores * (q.shape[-1] ** -0.5)
-    if causal:
-        mask = jnp.tril(jnp.ones((s, s), dtype=bool))
-        scores = jnp.where(mask[None, None], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(q.dtype), v)
-
-
 def segmented_agg(gids, values, num_groups: int, kind: str = "sum"):
     """gids [N] int32 (>= num_groups means dropped), values [N] f32."""
     valid = gids < num_groups

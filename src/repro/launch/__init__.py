@@ -1,1 +1,2 @@
-"""Launchers: production mesh, multi-pod dry-run, roofline, train/serve."""
+"""Launch helpers: the engine's worker mesh, the persistent compile cache
+and the chip's published roofline peaks."""

@@ -1,9 +1,9 @@
 """Where the repository's entry points keep JAX's persistent compile cache.
 
-Called by ``chip_smoke.py``, ``benchmarks/run.py`` and the examples, never
-at library import: a library user chooses their own cache. The directory
-is part of each entry's key, so it is one fixed path, never a temporary
-name, a pid or a timestamp.
+Called by ``chip_smoke.py`` and the examples, never at library import: a
+library user chooses their own cache. The directory is part of each
+entry's key, so it is one fixed path, never a temporary name, a pid or a
+timestamp.
 """
 
 from __future__ import annotations

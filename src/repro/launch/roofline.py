@@ -1,24 +1,16 @@
-"""Roofline analysis from compiled dry-run artifacts (no hardware needed).
+"""Roofline terms from a chip's published peaks.
 
-Terms per (arch x shape x mesh), in seconds, from the peaks of the chip
-named by its ``device_kind`` (``PEAKS``):
+Terms in seconds, from the peaks of the chip named by its ``device_kind``
+(``PEAKS``):
 
-    compute    = HLO_FLOPs      / (chips * peak FLOP/s)
-    memory     = HLO_bytes      / (chips * HBM bytes/s)
+    compute    = FLOPs          / (chips * peak FLOP/s)
+    memory     = bytes          / (chips * HBM bytes/s)
     collective = collective_B   / (chips * ICI bytes/s per link)
 
-cost_analysis() provides FLOPs/bytes; collective bytes are parsed from the
-post-SPMD HLO text (result-shape bytes of all-gather / all-reduce /
-reduce-scatter / all-to-all / collective-permute ops). The post-SPMD module
-is the per-device program, so parsed quantities are already per-chip and
-``roofline_terms`` is called with chips=1; MODEL_FLOPS comparisons divide
-the analytic global count by the chip count.
-
-(The old ``launch/perf.py`` hillclimb driver — a training-model variant
-sweep predating this repo's query-engine direction — was retired; its
-salvageable core, recording roofline terms against measured wall time for
-one compiled program, lives on as ``measure_program`` below, which
-``benchmarks/bench_kernels.py`` uses for per-kernel roofline fractions.)
+``collective_bytes`` parses collective bytes from post-SPMD HLO text
+(result-shape bytes of all-gather / all-reduce / reduce-scatter /
+all-to-all / collective-permute ops). The post-SPMD module is the
+per-device program, so parsed quantities are already per-chip.
 """
 
 from __future__ import annotations
@@ -105,59 +97,3 @@ def roofline_terms(flops: float, bytes_accessed: float,
 def dominant(terms: Dict[str, float]) -> str:
     return max(("compute_s", "memory_s", "collective_s"),
                key=lambda k: terms[k])
-
-
-def measure_program(fn, *args, warmup: int = 1, iters: int = 3,
-                    chips: int = 1) -> Dict[str, float]:
-    """Roofline-vs-measured report for one jittable program at one shape.
-
-    Lowers and compiles ``fn(*args)``, takes FLOPs / bytes-accessed from
-    the compiled cost analysis and collective bytes from the post-SPMD HLO
-    text, and compares the roofline time bound — the max of the
-    ``roofline_terms`` for the default device's kind — to the measured
-    per-call wall time. ``achieved_fraction`` is bound/measured: ~1.0
-    means the program runs at the hardware ceiling for its dominant term.
-    A device without published peaks (``PEAKS``), such as a CPU, raises
-    ``ValueError`` before anything runs.
-    """
-    import time
-
-    import jax
-
-    kind = jax.devices()[0].device_kind
-    peaks(kind)
-    jfn = jax.jit(fn)
-    compiled = jfn.lower(*args).compile()
-    cost = compiled.cost_analysis() or {}
-    flops = float(cost.get("flops", 0.0))
-    bytes_accessed = float(cost.get("bytes accessed", 0.0))
-    coll = sum(collective_bytes(compiled.as_text()).values())
-    terms = roofline_terms(flops, bytes_accessed, coll, chips=chips,
-                           device_kind=kind)
-    bound_s = max(terms.values())
-    for _ in range(warmup):
-        jax.block_until_ready(jfn(*args))
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        jax.block_until_ready(jfn(*args))
-    measured_s = (time.perf_counter() - t0) / iters
-    return {
-        "flops": flops,
-        "bytes_accessed": bytes_accessed,
-        "collective_bytes": coll,
-        "roofline_bound_s": bound_s,
-        "measured_s": measured_s,
-        "dominant": dominant(terms),
-        "achieved_fraction": bound_s / measured_s if measured_s else 0.0,
-    }
-
-
-def model_flops(cfg, shape) -> float:
-    """Analytic MODEL_FLOPS: 6*N*D train (fwd+bwd), 2*N*D prefill,
-    2*N_active*B decode (one token per sequence)."""
-    n_active = cfg.active_param_count()
-    if shape.kind == "train":
-        return 6.0 * n_active * shape.tokens
-    if shape.kind == "prefill":
-        return 2.0 * n_active * shape.tokens
-    return 2.0 * n_active * shape.global_batch

@@ -1,5 +1,5 @@
 """Engine-vs-oracle result comparison for TPC-H queries: the one
-comparator the tests, ``chip_smoke.py`` and the benchmarks share."""
+comparator the tests and ``chip_smoke.py`` share."""
 
 from __future__ import annotations
 

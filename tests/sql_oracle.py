@@ -20,6 +20,11 @@ surface (PK-covering equi-joins, int/dict group keys) so every generated
 query must agree with DuckDB -- a disagreement is an engine bug, never a
 "the fuzzer asked for too much" artifact.
 
+``mode_session`` and ``check_tpch_sql_text`` are the one body of the
+SQL-text TPC-H sweeps against the numpy oracle: one test file per session
+mode (``test_sql_frontend.py`` for streaming, ``test_sql_tpch_<mode>.py``
+for the others) and the DuckDB sweep in ``test_sql_oracle.py``.
+
 DuckDB is an *optional* dependency (the ``[sql]`` pyproject extra); import
 this module's ``require_duckdb`` in tests to skip loudly when absent.
 """
@@ -32,6 +37,10 @@ import random
 from typing import Dict, Iterable, List, Optional
 
 import numpy as np
+
+from repro.core import ICIExchange, Session
+from repro.tpch import oracle, sqltext
+from repro.tpch.compare import assert_results_match
 
 try:
     import duckdb
@@ -281,6 +290,35 @@ def check_sql(session, con, sql: str, rtol: float = 2e-3) -> Dict[str, str]:
     engine = qb.collect()
     duck = run_duckdb(con, sql)
     return diff_results(engine, duck, qb.schema, sql=sql, rtol=rtol)
+
+
+# ---------------------------------------------------------------------------
+# TPC-H SQL texts vs the numpy oracle, one session per mode
+# ---------------------------------------------------------------------------
+
+# streaming single worker; W=2 and W=4 over the ICI exchange (W=4 is the
+# mesh cell's worker count and protocol); the pallas kernel backend
+# (interpret mode off-TPU), which the single-chip cell runs
+MODES = ("streaming", "w2", "w4", "pallas")
+
+
+def mode_session(catalog, mode: str):
+    """The ``Session`` each SQL differential sweep runs ``mode`` on."""
+    if mode in ("w2", "w4"):
+        return Session(catalog, num_workers=int(mode[1:]),
+                       exchange=ICIExchange(), batch_rows=8192)
+    if mode == "pallas":
+        return Session(catalog, kernel_backend="pallas", batch_rows=16384)
+    if mode == "streaming":
+        return Session(catalog, batch_rows=16384)
+    raise ValueError(f"unknown mode {mode!r}; known: {MODES}")
+
+
+def check_tpch_sql_text(session, qnum: int, catalog, data) -> None:
+    """Run TPC-H query ``qnum`` from its SQL text on ``session`` and
+    assert its rows match the numpy oracle's over ``data``."""
+    res = session.sql(sqltext.sql_text(qnum, catalog)).collect()
+    assert_results_match(res, oracle.ORACLES[qnum](data), qnum)
 
 
 # ---------------------------------------------------------------------------

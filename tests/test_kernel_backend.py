@@ -97,24 +97,24 @@ def test_session_threads_backend_into_stats():
     assert stats["kernel_dispatch"] == {}
 
 
-def test_smoke_slice_matches_oracle_and_jnp():
+@pytest.mark.parametrize("qnum", [1, 3, 14])
+def test_smoke_slice_matches_oracle_and_jnp(qnum):
     """Q1/Q3/Q14 × W∈{1,2}: pallas rows == jnp rows == oracle rows, and
     the expected kernel kinds dispatched (plus 'partition' at W=2)."""
     data, catalog = dataset(SF)
-    for qnum in (1, 3, 14):
-        ref = oracle.ORACLES[qnum](data)
-        for w in (1, 2):
-            res_j, _ = run_backend(catalog, qnum, w, "jnp")
-            res_p, stats = run_backend(catalog, qnum, w, "pallas")
-            assert_results_match(res_p, ref, qnum)
-            assert_results_match(res_p, res_j, qnum)
-            kd = stats["kernel_dispatch"]
-            for kind in EXPECTED_KINDS[qnum]:
-                assert _dispatched(kd, kind), (qnum, w, kind, kd)
-            if w == 2 and qnum in (1, 3):
-                # Q1/Q3 shuffle on group keys at W=2 (Q14's global agg
-                # broadcasts instead, which has no metadata histogram)
-                assert kd.get("partition", 0) > 0, (qnum, kd)
+    ref = oracle.ORACLES[qnum](data)
+    for w in (1, 2):
+        res_j, _ = run_backend(catalog, qnum, w, "jnp")
+        res_p, stats = run_backend(catalog, qnum, w, "pallas")
+        assert_results_match(res_p, ref, qnum)
+        assert_results_match(res_p, res_j, qnum)
+        kd = stats["kernel_dispatch"]
+        for kind in EXPECTED_KINDS[qnum]:
+            assert _dispatched(kd, kind), (qnum, w, kind, kd)
+        if w == 2 and qnum in (1, 3):
+            # Q1/Q3 shuffle on group keys at W=2 (Q14's global agg
+            # broadcasts instead, which has no metadata histogram)
+            assert kd.get("partition", 0) > 0, (qnum, kd)
 
 
 def test_fused_morsel_dispatch_smoke():

@@ -12,44 +12,9 @@ from _hypothesis_compat import given, ints, sampled, seeded_given, settings, st
 
 from repro.kernels import ops, ref
 from repro.kernels.block_prefix_sum import block_prefix_sum
-from repro.kernels.flash_attention import flash_attention
 from repro.kernels.hash_probe import build_table, hash_probe
 from repro.kernels.radix_histogram import radix_histogram
 from repro.kernels.segmented_agg import GROUP_BLOCK, segmented_sum
-
-
-# ---------------------------------------------------------------------------
-# flash attention
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("b,h,s,d", [(1, 1, 128, 64), (2, 2, 256, 64),
-                                     (1, 2, 256, 128)])
-@pytest.mark.parametrize("causal", [True, False])
-def test_flash_attention_matches_ref(b, h, s, d, dtype, causal):
-    rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.normal(0, 1, (b, h, s, d)), dtype)
-    k = jnp.asarray(rng.normal(0, 1, (b, h, s, d)), dtype)
-    v = jnp.asarray(rng.normal(0, 1, (b, h, s, d)), dtype)
-    got = flash_attention(q, k, v, causal=causal, block_q=128, block_k=128,
-                          interpret=True)
-    want = ref.flash_attention(q, k, v, causal=causal)
-    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=tol, atol=tol)
-
-
-def test_flash_attention_block_shape_sweep():
-    rng = np.random.default_rng(1)
-    q = jnp.asarray(rng.normal(0, 1, (1, 1, 256, 64)), jnp.float32)
-    k = jnp.asarray(rng.normal(0, 1, (1, 1, 256, 64)), jnp.float32)
-    v = jnp.asarray(rng.normal(0, 1, (1, 1, 256, 64)), jnp.float32)
-    want = ref.flash_attention(q, k, v)
-    for bq, bk in [(64, 64), (128, 64), (64, 128), (256, 256)]:
-        got = flash_attention(q, k, v, block_q=bq, block_k=bk, interpret=True)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-5, atol=2e-5)
 
 
 # ---------------------------------------------------------------------------

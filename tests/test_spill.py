@@ -16,6 +16,7 @@ Three layers of coverage:
 """
 
 import os
+import tempfile
 
 import numpy as np
 import pytest
@@ -163,8 +164,8 @@ def test_close_removes_own_spill_dir():
               stacked=sampled(False, True),
               force_disk=sampled(False, True),
               seed=ints(0, 1 << 30))
-def test_tier_roundtrip_bit_exact(tmp_path, dtype_name, n_rows, stacked,
-                                  force_disk, seed):
+def test_tier_roundtrip_bit_exact(dtype_name, n_rows, stacked, force_disk,
+                                  seed):
     rng = np.random.default_rng(seed)
     shape = (2, n_rows) if stacked else (n_rows,)
     if dtype_name == "bytes":
@@ -184,17 +185,20 @@ def test_tier_roundtrip_bit_exact(tmp_path, dtype_name, n_rows, stacked,
         d = {"float32": dt.FLOAT32, "float64": dt.FLOAT64}[dtype_name]
         arr = rng.standard_normal(shape).astype(d.np_dtype())
     validity = rng.random(shape[:2] if stacked else shape) < 0.8
-    mgr = SpillManager(device_budget=0,
-                       host_budget=0 if force_disk else 1 << 30,
-                       spill_dir=str(tmp_path))
-    mgr.put_host("p", {"c": arr}, validity, {"c": d})
-    assert mgr.tier_of("p") == ("disk" if force_disk else "host")
-    cols, got_validity, schema = mgr.restore_host("p")
-    assert schema["c"].name == d.name
-    np.testing.assert_array_equal(got_validity, validity)
-    np.testing.assert_array_equal(cols["c"], arr)   # bit-exact
-    assert cols["c"].dtype == arr.dtype and cols["c"].shape == arr.shape
-    mgr.close()
+    # one directory per example: a function-scoped fixture would be shared
+    # by every example (and hypothesis refuses one under @given)
+    with tempfile.TemporaryDirectory() as spill_dir:
+        mgr = SpillManager(device_budget=0,
+                           host_budget=0 if force_disk else 1 << 30,
+                           spill_dir=spill_dir)
+        mgr.put_host("p", {"c": arr}, validity, {"c": d})
+        assert mgr.tier_of("p") == ("disk" if force_disk else "host")
+        cols, got_validity, schema = mgr.restore_host("p")
+        assert schema["c"].name == d.name
+        np.testing.assert_array_equal(got_validity, validity)
+        np.testing.assert_array_equal(cols["c"], arr)   # bit-exact
+        assert cols["c"].dtype == arr.dtype and cols["c"].shape == arr.shape
+        mgr.close()
 
 
 def test_spill_device_table_roundtrip():

@@ -9,8 +9,9 @@ Four layers, none needing optional dependencies:
   ``SqlUnsupportedError``/``SqlParseError`` *naming the construct*; the
   engine never silently returns wrong rows;
 * SQL-text TPC-H -- the 20 ported queries (``repro.tpch.sqltext``) run
-  end-to-end from their SQL text and match the numpy oracle (single
-  worker here; W=2 / pallas sweeps live in test_sql_oracle.py);
+  end-to-end from their SQL text and match the numpy oracle (streaming
+  single worker here; the W=2, W=4 and pallas modes run the same body,
+  ``sql_oracle.check_tpch_sql_text``, in ``test_sql_tpch_<mode>.py``);
 * unified execution API -- ``ExecutionOptions`` accepted consistently by
   ``collect``/``submit``/``run``/``Session.sql``, explain delegation, and
   the SQL-text plan/result cache key prefix.
@@ -19,14 +20,14 @@ Four layers, none needing optional dependencies:
 import numpy as np
 import pytest
 
-from repro.core import (ExecutionOptions, Session, SqlParseError,
-                        SqlUnsupportedError)
+from repro.core import ExecutionOptions, SqlParseError, SqlUnsupportedError
 from repro.core import plan as P
 from repro.core.builder import table as _t
 from repro.core.expr import col, date_lit, lit
 from repro.tpch import dbgen, oracle, sqltext
 
 from repro.tpch.compare import assert_results_match
+from sql_oracle import check_tpch_sql_text, mode_session
 
 SF = 0.002
 
@@ -43,7 +44,7 @@ def catalog():
 
 @pytest.fixture(scope="module")
 def session(catalog):
-    return Session(catalog, batch_rows=16384)
+    return mode_session(catalog, "streaming")
 
 
 def _fp(qb, session):
@@ -176,8 +177,7 @@ class TestLoudFailures:
 
 @pytest.mark.parametrize("qnum", sqltext.SUPPORTED)
 def test_tpch_from_sql_text(qnum, session, catalog, data):
-    res = session.sql(sqltext.sql_text(qnum, catalog)).collect()
-    assert_results_match(res, oracle.ORACLES[qnum](data), qnum)
+    check_tpch_sql_text(session, qnum, catalog, data)
 
 
 def test_at_least_15_queries_ported():

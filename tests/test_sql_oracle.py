@@ -1,18 +1,19 @@
 """SQL cross-engine differential sweeps (``-m sql_oracle``; own CI job).
 
-Three layers, all driven from *SQL text* through ``Session.sql``:
+Two layers, both driven from *SQL text* through ``Session.sql``:
 
-* the 20 ported TPC-H texts vs the hand-written numpy oracle on every
-  backend mode -- streaming single-worker, distributed W=2 (ICI
-  exchange), and the pallas kernel backend (interpret mode off-TPU);
-* the same texts vs in-process DuckDB (row counts + per-column
-  checksums, ``tests/sql_oracle.py``) across the same three modes;
+* the 20 ported TPC-H texts vs in-process DuckDB (row counts +
+  per-column checksums, ``tests/sql_oracle.py``) on three session modes
+  -- streaming single-worker, distributed W=2 (ICI exchange), and the
+  pallas kernel backend (interpret mode off-TPU);
 * a seeded SQL fuzzer over the TPC-H schema diffed against DuckDB --
   plan shapes TPC-H never exercises.
 
-DuckDB layers skip loudly when the ``[sql]`` extra is not installed; the
-numpy-oracle sweeps always run. Checksums accumulate into
-``results/sql_oracle/checksums_<mode>.json`` (the CI artifact).
+The same texts against the numpy oracle run in tier-1, one file per mode
+(``test_sql_frontend.py``, ``test_sql_tpch_<mode>.py``). Both layers here
+skip loudly when the ``[sql]`` extra is not installed. Checksums
+accumulate into ``results/sql_oracle/checksums_<mode>.json`` (the CI
+artifact).
 
 Env knobs: ``SQL_ORACLE_SF`` (default 0.002), ``SQL_ORACLE_FUZZ_N``
 (default 24), ``SQL_ORACLE_SEED`` (default 7).
@@ -24,13 +25,12 @@ import pathlib
 
 import pytest
 
-from repro.core import ICIExchange, Session
-from repro.tpch import dbgen, oracle, sqltext
+from repro.core import Session
+from repro.tpch import dbgen, sqltext
 
 from sql_oracle import (HAVE_DUCKDB, SqlMismatch, check_sql,
-                        connect_with_catalog, fuzz_queries, require_duckdb,
-                        run_duckdb)
-from repro.tpch.compare import assert_results_match
+                        connect_with_catalog, fuzz_queries, mode_session,
+                        require_duckdb, run_duckdb)
 
 pytestmark = pytest.mark.sql_oracle
 
@@ -44,27 +44,13 @@ _checksums = {m: {} for m in MODES}
 
 
 @pytest.fixture(scope="module")
-def data():
-    return dbgen.generate(sf=SF)
-
-
-@pytest.fixture(scope="module")
 def catalog():
     return dbgen.load_catalog(sf=SF)
 
 
-def _session(catalog, mode: str) -> Session:
-    if mode == "w2":
-        return Session(catalog, num_workers=2, exchange=ICIExchange(),
-                       batch_rows=8192)
-    if mode == "pallas":
-        return Session(catalog, kernel_backend="pallas", batch_rows=16384)
-    return Session(catalog, batch_rows=16384)
-
-
 @pytest.fixture(scope="module")
 def sessions(catalog):
-    return {m: _session(catalog, m) for m in MODES}
+    return {m: mode_session(catalog, m) for m in MODES}
 
 
 @pytest.fixture(scope="module")
@@ -84,17 +70,6 @@ def _dump_checksums():
         if sums:
             (out / f"checksums_{mode}.json").write_text(
                 json.dumps(sums, indent=2, sort_keys=True))
-
-
-# ---------------------------------------------------------------------------
-# TPC-H SQL texts vs the numpy oracle, all three backend modes
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("mode", MODES)
-@pytest.mark.parametrize("qnum", sqltext.SUPPORTED)
-def test_tpch_sql_vs_numpy_oracle(qnum, mode, sessions, catalog, data):
-    res = sessions[mode].sql(sqltext.sql_text(qnum, catalog)).collect()
-    assert_results_match(res, oracle.ORACLES[qnum](data), qnum)
 
 
 # ---------------------------------------------------------------------------

@@ -1,14 +1,9 @@
 """End-to-end behaviour tests for the paper's system: the full path from
-storage through device-resident operators and exchange to results, plus the
-training stack wired to the engine's data layer."""
+storage through device-resident operators and exchange to results."""
 
 import numpy as np
 
-import jax
-
-from repro.core import HostExchange, ICIExchange, Session, dtypes as dt
-from repro.core import plan as P
-from repro.core.expr import col
+from repro.core import HostExchange, ICIExchange, Session
 from repro.tpch import dbgen, oracle, queries
 
 
@@ -51,46 +46,6 @@ def test_driver_adaptation_inserts_conversions():
     res = session.execute(plan)
     assert len(res["sum_qty"]) == 4
     assert session.last_driver.conversion_stats.get("bytes", 0) > 0
-
-
-def test_engine_feeds_training_data():
-    """The engine is the framework's data substrate: filter/dedup a token
-    table with a query, train on the result (paper's technique as the
-    input pipeline)."""
-    from repro.configs import get_config
-    from repro.data import TokenPipeline
-    from repro.models import build_model
-    from repro.train import make_train_step, train_state_init
-
-    rng = np.random.default_rng(0)
-    catalog = dbgen.load_catalog(sf=0.001)
-    catalog.register_numpy(
-        "corpus",
-        {"doc": np.repeat(np.arange(200), 50),
-         # skewed (Zipf-flavored) tokens: a uniform vocab draw has no
-         # learnable structure, leaving the loss pinned at ln(V) and the
-         # loss-decreases assertion to initialization luck
-         "tok": (rng.random(10_000) ** 4 * 512).astype(np.int64),
-         "quality": rng.random(10_000).astype(np.float32)},
-        {"doc": dt.INT32, "tok": dt.INT32, "quality": dt.FLOAT32})
-    plan = P.Project(P.Filter(P.TableScan("corpus"),
-                              col("quality") > 0.2), [("tok", col("tok"))])
-    filtered = Session(catalog, num_workers=2, batch_rows=4096).execute(plan)
-    tokens = filtered["tok"]
-    assert len(tokens) > 2_000
-
-    model = build_model(get_config("qwen2_1_5b", smoke=True))
-    state = train_state_init(model, jax.random.key(0))
-    # lr/steps sized so the unigram skew is actually learned: the descent
-    # below ln(V) needs ~10 steps to clear per-batch noise
-    step = jax.jit(make_train_step(model, base_lr=1e-2))
-    pipe = TokenPipeline(tokens, batch=2, seq_len=32)
-    losses = []
-    for _ in range(16):
-        state, m = step(state, next(pipe))
-        losses.append(float(m["loss"]))
-    assert np.isfinite(losses).all()
-    assert losses[-1] < losses[0]
 
 
 def test_chip_smoke_refuses_without_tpu(tmp_path):
