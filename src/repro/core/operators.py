@@ -346,7 +346,7 @@ def _finalize_avg(table: DeviceTable, user_specs):
 # per-query operator counters (``executor_stats()["agg"]``, ``["join"]``):
 # the driver opens a scope around each query, as it does for kernel
 # dispatches, and the operators report into it from host-side state
-AGG_COUNTERS = ("partials", "merges", "merge_rows")
+AGG_COUNTERS = ("partials", "merges", "merge_rows", "compacted_merges")
 JOIN_COUNTERS = ("dense_probes", "sorted_probes", "index_slots")
 _stats_tls = threading.local()
 
@@ -387,6 +387,21 @@ def _rows(table: DeviceTable) -> int:
     return int(table.validity.shape[-1])
 
 
+@jax.jit
+@functools.partial(tracing.program, "agg")
+def _valid_counts(validities):
+    """Valid rows of each table (``[n]``, or ``[n, W]`` for stacked)."""
+    return jnp.stack([jnp.sum(v.astype(jnp.int32), axis=-1)
+                      for v in validities])
+
+
+@table_op(family="agg")
+def _prefix(table: DeviceTable, rows: int):
+    """The first ``rows`` slots of ``table``."""
+    return DeviceTable({n: a[:rows] for n, a in table.columns.items()},
+                       table.validity[:rows], table.schema)
+
+
 class HashAggregation(Operator):
     """Concatenation-based streaming aggregation (paper §3.2).
 
@@ -403,9 +418,15 @@ class HashAggregation(Operator):
     a merge has no more groups than the slots it reads, so every emission
     and flush happens at the batch it would if each batch were merged.
 
+    A table's static slots bound its groups, but ``rel.group_rows`` packs
+    the groups it holds into a prefix. ``finish`` reads the valid counts
+    of the held tables (one host read) and merges only each table's first
+    ``B`` slots, ``B`` the power of two at or above the largest count:
+    exact, and one program shape however the counts fall below ``B``.
+
     Counted per query (``collect_agg_stats``): ``partials`` (batches
     aggregated), ``merges`` (re-aggregations), ``merge_rows`` (slots fed to
-    merges).
+    merges), ``compacted_merges`` (merges of occupied prefixes).
 
     mode: 'partial'  emits partial columns (avg -> sum+cnt) for an exchange
           'final'    merges partial columns after an exchange
@@ -467,6 +488,16 @@ class HashAggregation(Operator):
         return _aggregate(merged, self.group_keys, merge_specs(self.specs),
                           min(self.max_groups, rows))
 
+    @staticmethod
+    def _occupied(tables: List[DeviceTable]) -> List[DeviceTable]:
+        """Each of ``tables`` cut to its first ``B`` slots, where ``B`` is
+        the power of two at or above the most valid rows any table (or
+        worker) holds. Exact: an aggregated table's groups fill a prefix."""
+        counts = np.asarray(_valid_counts([t.validity for t in tables]))
+        b = _pow2(counts.max())
+        _count("agg", "compacted_merges")
+        return [_prefix(t, b) if _rows(t) > b else t for t in tables]
+
     def add_input(self, batch):
         self._held.append(_aggregate(batch, self.group_keys, self.specs,
                                      min(self.max_groups, _rows(batch))))
@@ -487,7 +518,10 @@ class HashAggregation(Operator):
         return []
 
     def finish(self):
-        acc = self._merge(self._held) if self._held else None
+        held = self._held
+        if len(held) > 1:
+            held = self._occupied(held)
+        acc = self._merge(held) if held else None
         self._held = []
         # restore the flushed runs one at a time and merge each into the
         # accumulator (device working set stays at two max_groups tables

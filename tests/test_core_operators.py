@@ -262,25 +262,40 @@ def _agg_batches(rng, mode, max_groups, rows, n_batches, workers):
     in ``[0, max_groups)``; worker-stacked ``[workers, rows]`` when
     ``workers``; final mode gets partial columns."""
     def one():
-        live = rows - 3
-        data = {"k": rng.integers(0, max_groups, live).astype(np.int32)}
-        if mode == "final":
-            data.update(s=rng.random(live).astype(np.float32),
-                        c=rng.integers(1, 5, live).astype(np.int32),
-                        m=rng.random(live).astype(np.float32))
-            return _table(data, _FINAL_SCHEMA, rows)
-        data["v"] = rng.random(live).astype(np.float32)
-        return _table(data, _SCHEMA, rows)
+        keys = rng.integers(0, max_groups, rows - 3).astype(np.int32)
+        return _keyed_table(rng, mode, keys, rows)
 
     if not workers:
         return [one() for _ in range(n_batches)]
-    out = []
-    for _ in range(n_batches):
-        ts = [one() for _ in range(workers)]
-        out.append(DeviceTable(
-            {n: jnp.stack([t.columns[n] for t in ts]) for n in ts[0].columns},
-            jnp.stack([t.validity for t in ts]), ts[0].schema))
-    return out
+    return [_stack([one() for _ in range(workers)]) for _ in range(n_batches)]
+
+
+def _keyed_table(rng, mode, keys, rows):
+    """A table of ``rows`` slots whose valid rows carry ``keys`` and random
+    values (partial columns in final mode)."""
+    live, data = len(keys), {"k": keys}
+    if mode == "final":
+        data.update(s=rng.random(live).astype(np.float32),
+                    c=rng.integers(1, 5, live).astype(np.int32),
+                    m=rng.random(live).astype(np.float32))
+        return _table(data, _FINAL_SCHEMA, rows)
+    data["v"] = rng.random(live).astype(np.float32)
+    return _table(data, _SCHEMA, rows)
+
+
+def _stack(ts):
+    """Worker-stacked ``[len(ts), rows]`` table of local tables ``ts``."""
+    return DeviceTable(
+        {n: jnp.stack([t.columns[n] for t in ts]) for n in ts[0].columns},
+        jnp.stack([t.validity for t in ts]), ts[0].schema)
+
+
+def _occupied_slots(batches):
+    """The power of two at or above the most distinct valid keys any
+    batch (or worker) holds, at least 1."""
+    most = max(len(np.unique(cols["k"][val]))
+               for b in batches for cols, val in _workers(b))
+    return 1 << max(most - 1, 0).bit_length()
 
 
 def _by_key(cols, validity):
@@ -343,11 +358,16 @@ def test_streaming_aggregation_merges_at_threshold(
         streamed = dict(counts)
         out = agg.finish()[0]
     assert counts["partials"] == n_batches
+    # only finish's merge of several tables reads the occupied prefixes
+    assert streamed.get("compacted_merges", 0) == 0
+    assert counts.get("compacted_merges", 0) == \
+        counts["merges"] - streamed.get("merges", 0)
     slots = min(max_groups, rows)
     if n_batches * slots < 2 * max_groups:
         assert streamed.get("merges", 0) == 0
         assert counts["merges"] == 1
-        assert counts["merge_rows"] == n_batches * slots
+        assert counts["merge_rows"] == \
+            n_batches * min(slots, _occupied_slots(batches))
     elif rows >= max_groups:
         assert streamed["merges"] == counts["merges"] == n_batches - 1
         assert counts["merge_rows"] == (n_batches - 1) * 2 * max_groups
@@ -359,6 +379,54 @@ def test_streaming_aggregation_merges_at_threshold(
         got = _by_key(cols, val)
         _assert_groups(got, _numpy_groups(batches, mode, w), rtol=1e-5)
         _assert_groups(got, _by_key(fcols, fval), rtol=1e-6)
+
+
+def _counted_batches(rng, mode, counts, rows):
+    """One batch of ``rows`` slots per entry of ``counts``, holding exactly
+    that many distinct keys of ``[0, 64)`` (no valid row for 0; every slot
+    valid where it equals ``rows``, else the last 3 dead); an entry that
+    is a list gives a worker-stacked batch, one count per worker."""
+    def one(n):
+        live = 0 if n == 0 else rows if n == rows else rows - 3
+        keys = rng.choice(64, n, replace=False).astype(np.int32)
+        return _keyed_table(rng, mode, rng.permutation(keys[np.concatenate(
+            [np.arange(n), rng.integers(0, max(n, 1), live - n)])]), rows)
+
+    return [_stack([one(n) for n in c]) if isinstance(c, list) else one(c)
+            for c in counts]
+
+
+@pytest.mark.parametrize("mode", ["single", "partial", "final"])
+@pytest.mark.parametrize("counts,occupied", [
+    ([5, 3, 9], 16),                     # local: cut to 16 of 32 slots
+    ([[2, 7], [3, 1], [4, 5]], 8),       # W=2, workers hold other counts
+    ([5, 0, 3], 8),                      # a partial with no valid rows
+    ([[0, 6], [0, 2]], 8),               # a worker with no valid rows
+    ([0, 0], 1),                         # nothing valid anywhere
+    ([32, 4, 6], 32),                    # a partial full to its slots
+])
+def test_finish_merges_occupied_prefix(mode, counts, occupied):
+    """finish merges each held partial's first B slots, B the power of two
+    at or above the most groups any partial (or worker) holds, with the
+    groups numpy gives."""
+    rows, max_groups = 32, 64
+    batches = _counted_batches(np.random.default_rng(len(counts) + occupied),
+                               mode, counts, rows)
+    assert _occupied_slots(batches) == occupied
+    agg = ops.HashAggregation(["k"], _AGGS, mode, max_groups=max_groups)
+    stats = {}
+    agg.open()
+    with ops.collect_agg_stats(stats):
+        for b in batches:
+            assert agg.add_input(b) == []
+        out = agg.finish()[0]
+    merged = len(batches) * min(occupied, rows)
+    assert stats == {"partials": len(batches), "merges": 1,
+                     "merge_rows": merged, "compacted_merges": 1}
+    assert ops._rows(out) == min(max_groups, merged)
+    for w, (cols, val) in enumerate(_workers(out)):
+        _assert_groups(_by_key(cols, val), _numpy_groups(batches, mode, w),
+                       rtol=1e-5)
 
 
 @pytest.mark.parametrize("point", ["spill", "emit"])

@@ -95,16 +95,23 @@ def test_query_spans_carry_one_query_id(tmp_path):
     assert sum(stats.values()) <= handle.latency
 
 
-@pytest.mark.parametrize("keys, merges", [
-    ("l_orderkey", "once"),          # groups bounded by the row count
-    ("l_returnflag", "per_morsel"),  # groups far below a morsel's rows
+@pytest.mark.parametrize("keys, where, merges", [
+    # groups bounded by the row count
+    pytest.param("l_orderkey", "", "once", id="l_orderkey-once"),
+    # groups far below a morsel's rows
+    pytest.param("l_returnflag", "", "per_morsel",
+                 id="l_returnflag-per_morsel"),
+    # as the first, with a few groups in each morsel's slots
+    pytest.param("l_orderkey", "WHERE l_quantity < 5", "once",
+                 id="l_orderkey-once-sparse"),
 ])
-def test_agg_counters_per_query(keys, merges):
-    sess = Session(dbgen.load_catalog(sf=0.002), batch_rows=2048,
+def test_agg_counters_per_query(keys, where, merges):
+    catalog = dbgen.load_catalog(sf=0.002)
+    sess = Session(catalog, batch_rows=2048,
                    scheduler_config=SchedulerConfig(plan_cache_size=0,
                                                     cache_results=False))
     qb = sess.sql(f"SELECT {keys}, sum(l_quantity) AS q FROM lineitem "
-                  f"GROUP BY {keys}")
+                  f"{where} GROUP BY {keys}")
     handle = qb.submit()
     handle.result()
     sess.scheduler().close()
@@ -113,14 +120,25 @@ def test_agg_counters_per_query(keys, merges):
     agg = stats["agg"]
     assert morsels >= 3 and agg["partials"] == morsels
     if merges == "once":
-        # every partial's slots, the last morsel's fewer than 2048
-        assert agg["merges"] == 1
-        assert (morsels - 1) * 2048 < agg["merge_rows"] <= morsels * 2048
+        # finish merges each partial's first B slots, B the power of two
+        # at or above the most groups a morsel holds; the last morsel has
+        # fewer than 2048 slots
+        data = catalog.get("lineitem").data
+        keep = data["l_quantity"] < 5 if where else np.ones(
+            len(data["l_quantity"]), bool)
+        parts = [(data[keys][i:i + 2048], keep[i:i + 2048])
+                 for i in range(0, len(keep), 2048)]
+        b = 1 << (max(len(np.unique(k[m])) for k, m in parts)
+                  - 1).bit_length()
+        assert len(parts) == morsels
+        assert agg["merges"] == agg["compacted_merges"] == 1
+        assert agg["merge_rows"] == sum(min(b, len(k)) for k, _ in parts)
     else:
         assert agg["merges"] == morsels - 1
-    assert "agg: partials=%d merges=%d merge_rows=%d" % (
-        agg["partials"], agg["merges"], agg["merge_rows"]) in \
-        qb.explain(analyze=True)
+        assert agg["compacted_merges"] == 0
+    assert ("agg: partials=%d merges=%d merge_rows=%d compacted_merges=%d"
+            % (agg["partials"], agg["merges"], agg["merge_rows"],
+               agg["compacted_merges"])) in qb.explain(analyze=True)
 
 
 def test_program_counts_each_trace_per_family():
