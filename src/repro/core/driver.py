@@ -443,7 +443,7 @@ class Driver:
         sound as a warm ``max_matches``; hashed composite keys are never
         tightened."""
         kt = [build.schema[k] for k in node.build_keys]
-        if len(kt) != 1 or kt[0].name not in ("int32", "date32", "dict32"):
+        if len(kt) != 1 or kt[0].name not in ops.INT_KEY_DTYPES:
             return
         keys = np.asarray(build.columns[node.build_keys[0]])
         valid = np.asarray(build.validity)

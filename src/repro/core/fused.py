@@ -70,13 +70,12 @@ def stages_in_kernel(stages: Sequence[Stage]) -> bool:
 
 
 def probe_key(table: DeviceTable, key_names, pack, empty_key: int):
-    """Single-lane probe key: the raw int key, or the injective composite
-    pack (``relational.packed_key``) when ``pack`` is set."""
+    """Single-lane join key: ``relational.packed_key`` when ``pack`` is
+    set, else ``relational.join_key`` (the raw int column, or a hash)."""
     cols = [table.columns[k] for k in key_names]
     if pack is not None:
         return rel.packed_key(cols, pack, empty_key=empty_key)
-    key, _ = rel.join_key(cols)
-    return key
+    return rel.join_key(cols)
 
 
 def _block_spec(shape, row_block):

@@ -26,6 +26,7 @@ from __future__ import annotations
 import contextlib
 import functools
 import itertools
+import math
 import threading
 import time
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
@@ -347,7 +348,8 @@ def _finalize_avg(table: DeviceTable, user_specs):
 # the driver opens a scope around each query, as it does for kernel
 # dispatches, and the operators report into it from host-side state
 AGG_COUNTERS = ("partials", "merges", "merge_rows", "compacted_merges")
-JOIN_COUNTERS = ("dense_probes", "sorted_probes", "index_slots")
+JOIN_COUNTERS = ("dense_probes", "sorted_probes", "index_slots",
+                 "hashed_builds")
 _stats_tls = threading.local()
 
 
@@ -371,9 +373,10 @@ def collect_agg_stats(counts: Dict[str, int]):
 
 
 def collect_join_stats(counts: Dict[str, int]):
-    """Add the jnp-path HashJoin's counters (``JOIN_COUNTERS``) into
-    ``counts`` for the duration of the scope, on this thread: probe calls
-    by lookup (index or binary search) and the largest index built."""
+    """Add HashJoin's counters (``JOIN_COUNTERS``) into ``counts`` for
+    the duration of the scope, on this thread: jnp-path probe calls by
+    lookup (index or binary search), the largest index built, and the
+    sealed builds whose key is hashed."""
     return _collect("join", counts)
 
 
@@ -573,51 +576,66 @@ EMPTY_KEY = -1
 # keeps the binary search
 MAX_INDEX_SLOTS_PER_ROW = 16
 
-
-@table_op(family="join")
-def _build_join_table(build: DeviceTable, build_keys, slots: int = 0):
-    key, _ = rel.join_key([build.columns[k] for k in build_keys])
-    return rel.join_build(key, build.validity, slots)
-
-
-@table_op(family="join")
-def _join_key_range(build: DeviceTable, build_keys):
-    """(min, max) of the valid build keys; (INT32_MAX, INT32_MIN) if none."""
-    key, _ = rel.join_key([build.columns[k] for k in build_keys])
-    info = jnp.iinfo(jnp.int32)
-    return jnp.stack([jnp.min(jnp.where(build.validity, key, info.max)),
-                      jnp.max(jnp.where(build.validity, key, info.min))])
-
-
-def _index_slots(build: DeviceTable, build_keys) -> int:
-    """Slots S of the sorted build's direct-address index, or 0 for the
-    binary search. S is the power of two at or above ``max - min + 2`` of
-    the valid keys (over all workers of a stacked build, so every worker
-    shares one static shape), taken while it stays within
-    ``MAX_INDEX_SLOTS_PER_ROW`` times the build's per-worker row slots.
-    A valid INT32_MAX key keeps the binary search: it would share its
-    sorted run with the padded invalid rows, which the index does not
-    count."""
-    rng = np.asarray(_join_key_range(build, build_keys)).reshape(-1, 2)
-    lo, hi = int(rng[:, 0].min()), int(rng[:, 1].max())
-    if hi < lo or hi == np.iinfo(np.int32).max:
-        return 0
-    slots = _pow2(hi - lo + 2)
-    cap = _pow2(build.validity.shape[-1])
-    return slots if slots <= MAX_INDEX_SLOTS_PER_ROW * cap else 0
+# int-like key columns: raw when alone, packable when composite
+INT_KEY_DTYPES = ("int32", "date32", "dict32")
 
 
 def _join_probe_key(table: DeviceTable, key_names, pack):
-    """Single-lane join key for the open-addressing table: the raw
-    int-like column, or the injective composite pack. Packed keys are
-    nonnegative by construction, so they can never alias the empty
-    sentinel; out-of-range probe values map *to* the sentinel and are
-    masked to no-match by the callers."""
-    cols = [table.columns[k] for k in key_names]
-    if pack is not None:
-        return rel.packed_key(cols, pack, empty_key=EMPTY_KEY)
-    key, _ = rel.join_key(cols)
-    return key
+    """The join key as ``HashJoin.seal_build`` encoded it, for build and
+    probe on both backends: one int-like column raw, a composite packed
+    into its ``pack`` windows (tuples outside them give ``EMPTY_KEY``),
+    anything else hashed by ``relational.join_key``."""
+    return fused.probe_key(table, key_names, pack, EMPTY_KEY)
+
+
+@table_op(family="join")
+def _key_ranges(build: DeviceTable, build_keys):
+    """Per key column, (min, max) over the valid build rows (``[n, 2]``);
+    (INT32_MAX, INT32_MIN) where no row is valid."""
+    cols = jnp.stack([build.columns[k].astype(jnp.int32) for k in build_keys])
+    lo = jnp.min(jnp.where(build.validity, cols, rel.INT32_MAX), axis=-1)
+    hi = jnp.max(jnp.where(build.validity, cols, -rel.INT32_MAX - 1), axis=-1)
+    return jnp.stack([lo, hi], axis=-1)
+
+
+def _read_ranges(build: DeviceTable, build_keys) -> List[Tuple[int, int]]:
+    """``_key_ranges`` read to the host in one transfer, over all workers
+    of a stacked build (so every worker shares one encoding)."""
+    rng = np.asarray(_key_ranges(build, build_keys)).reshape(
+        -1, len(build_keys), 2)
+    return list(zip(rng[..., 0].min(0).tolist(), rng[..., 1].max(0).tolist()))
+
+
+def _pack_windows(ranges):
+    """``((lo, span), ...)`` per key column for ``relational.packed_key``,
+    or None when the spans' product passes the int32 key lane. A build
+    with no valid row gets the window (0, 1) per column."""
+    spans = [max(hi - lo, 0) + 1 for lo, hi in ranges]
+    if math.prod(spans) > rel.INT32_MAX:
+        return None
+    return tuple((lo if hi >= lo else 0, s) for (lo, hi), s in zip(ranges, spans))
+
+
+def _index_slots(lo: int, hi: int, rows: int) -> int:
+    """Slots S of the direct-address index over the valid keys' range
+    ``[lo, hi]``: the power of two at or above ``hi - lo + 2`` while it is
+    within ``MAX_INDEX_SLOTS_PER_ROW`` times the per-worker row slots, else
+    0 (binary search), as for an empty build or a valid INT32_MAX key (its
+    sorted run would take in the padded invalid rows, which S omits)."""
+    if hi < lo or hi == rel.INT32_MAX:
+        return 0
+    slots = _pow2(hi - lo + 2)
+    return slots if slots <= MAX_INDEX_SLOTS_PER_ROW * _pow2(rows) else 0
+
+
+@table_op(family="join")
+def _build_join_table(build: DeviceTable, build_keys, pack=None,
+                      slots: int = 0, runs: bool = False):
+    """The sorted build (``relational.join_build``); with ``runs``, also
+    its longest run of equal keys among valid rows."""
+    key = _join_probe_key(build, build_keys, pack)
+    bt = rel.join_build(key, build.validity, slots)
+    return (bt, rel.longest_run(bt)) if runs else bt
 
 
 @table_op(family="join")
@@ -626,39 +644,6 @@ def _build_hash_table(build: DeviceTable, build_keys, table_size: int, pack):
     rows = jnp.arange(key.shape[0], dtype=jnp.int32)
     return kernel_ops.build_table(key, rows, table_size,
                                   empty_key=EMPTY_KEY, valid=build.validity)
-
-
-_PACKABLE_DTYPES = ("int32", "date32", "dict32")
-
-
-def _derive_pack(build: DeviceTable, build_keys):
-    """Host-side injective-pack windows for a composite int-like key.
-
-    Returns ``((lo, span), ...)`` per key column — derived from the valid
-    build rows' min/max (worker-stacked builds share one global window, a
-    sound superset per worker) — or None when any column is not int-like
-    or the windows' product overflows the int32 key lane. The resulting
-    ``relational.packed_key`` is injective over in-window tuples, so no
-    post-probe verification is needed; every valid build row is in-window
-    by construction, and probe tuples outside any window pack to the empty
-    sentinel (no build key can match them).
-    """
-    cols = []
-    for k in build_keys:
-        if build.schema[k].name not in _PACKABLE_DTYPES:
-            return None
-        cols.append(np.asarray(build.columns[k]).reshape(-1))
-    valid = np.asarray(build.validity).reshape(-1)
-    pack, prod = [], 1
-    for c in cols:
-        vals = c[valid]
-        lo = int(vals.min()) if vals.size else 0
-        span = int(vals.max()) - lo + 1 if vals.size else 1
-        prod *= span
-        if prod > np.iinfo(np.int32).max:
-            return None
-        pack.append((lo, span))
-    return tuple(pack)
 
 
 def _probe_bound(table_keys: np.ndarray) -> int:
@@ -805,9 +790,13 @@ def _expand_join_output(probe: DeviceTable, build_table: DeviceTable,
 
 @table_op(n_tables=2, family="join")
 def _probe_join(probe: DeviceTable, build_state, probe_keys, build_keys,
-                build_payload, join_type: str, max_matches: int, exact: bool):
+                build_payload, join_type: str, max_matches: int, pack,
+                exact: bool):
+    """Sorted-key probe: exact semi/anti joins by ``semi_mask``, else up to
+    ``max_matches`` candidates per probe row, which a hashed key keeps only
+    where the original columns are equal (bucket-then-verify)."""
     build_table, bt = build_state
-    key, _ = rel.join_key([probe.columns[k] for k in probe_keys])
+    key = _join_probe_key(probe, probe_keys, pack)
 
     if join_type in ("left_semi", "left_anti") and exact:
         mask = rel.semi_mask(bt, key, probe.validity)
@@ -817,7 +806,7 @@ def _probe_join(probe: DeviceTable, build_state, probe_keys, build_keys,
 
     res = rel.join_probe(bt, key, probe.validity, max_matches)
     valid = res.valid
-    if not exact:   # hashed keys: verify true equality (bucket-then-verify)
+    if not exact:
         for pk, bk in zip(probe_keys, build_keys):
             pv = jnp.take(probe.columns[pk], res.probe_idx, axis=0)
             bv = jnp.take(build_table.columns[bk], res.build_idx, axis=0)
@@ -830,33 +819,35 @@ def _probe_join(probe: DeviceTable, build_state, probe_keys, build_keys,
 class HashJoin(Operator):
     """Streaming probe against a fully materialized build side.
 
-    TPU adaptation of cuDF's hash join, with a per-session kernel backend
-    (``kernels.ops.current_backend()``, sampled at ``seal_build``):
+    TPU adaptation of cuDF's hash join. ``seal_build`` decides the key's
+    encoding once for both backends (``_join_probe_key``): one int-like
+    column stays raw; a composite int-like key whose value windows (one
+    host read of each column's valid min and max, ``_key_ranges``) have a
+    product within int32 packs injectively; both are exact. Anything else
+    is hashed and verified after the probe, and a semi/anti join on it
+    reads every candidate of its hash run (the build's longest, measured
+    once by ``relational.longest_run``, rounded up to a power of two).
+    The probe state follows the backend (``kernels.ops.current_backend()``
+    at ``seal_build``):
 
-    * 'jnp'    -- the build side becomes a sorted key array (doubles as
-                  the oracle). An exact key whose valid range spans at
-                  most ``MAX_INDEX_SLOTS_PER_ROW`` slots per build row
-                  also gets a direct-address prefix count over that
-                  range, read once per probe key; other builds are
-                  probed with searchsorted;
-    * 'pallas' -- exact int-like keys build an open-addressing table
+    * 'pallas' -- an exact key builds an open-addressing table
                   (``kernels.build_table``, power-of-two slots sized 2x
-                  the planner's ``build_rows`` bound). Composite int-like
-                  keys pack injectively into one int32 lane when their
-                  value windows fit (``_derive_pack``). Semi/anti and
+                  the planner's ``build_rows`` bound). Semi/anti and
                   ``max_matches == 1`` joins probe with ``hash_probe``;
-                  expansion joins probe with ``hash_probe_multi`` (static
-                  ``P x max_matches`` output, same layout as the jnp
-                  path). Non-integer keys, unpackably wide composites,
-                  build keys equal to the empty sentinel (-1) and
-                  oversized builds fall back to the jnp path; probe keys
-                  equal to the sentinel are masked to no-match (no such
-                  key can occupy the table).
+                  expansion joins with ``hash_probe_multi`` (static
+                  ``P x max_matches`` output, the jnp path's layout).
+                  Hashed keys, build keys equal to the empty sentinel (-1)
+                  and builds past ``MAX_HASH_TABLE_SLOTS`` take the jnp
+                  path; probe keys equal to the sentinel never match;
+    * 'jnp'    -- the build side becomes a sorted key array (doubles as
+                  the oracle), with a direct-address prefix count over an
+                  exact key's range (a packed key's: its windows' product)
+                  where that spans at most ``MAX_INDEX_SLOTS_PER_ROW``
+                  slots per build row; other builds are probed with
+                  searchsorted.
 
-    Hashed multi-column keys on the jnp path are verified after the probe,
-    as in a bucketed hash join (packed composites need no verification —
-    the pack is injective). ``max_matches`` is the planner's
-    expansion-capacity hint; the oracle tests assert it is never exceeded.
+    ``max_matches`` is the planner's expansion-capacity hint; the oracle
+    tests assert it is never exceeded.
     """
 
     name = "HashJoin"
@@ -878,8 +869,9 @@ class HashJoin(Operator):
         self._hash_state = None          # (build, table_keys, table_vals)
         self._max_probes = 0
         self._exact = True
-        self._index_slots = 0            # jnp path: direct-address index
         self._pack = None                # composite-key windows, or None
+        self._matches = max_matches      # jnp path: candidates per probe row
+        self._index_slots = 0            # jnp path: direct-address index
         self._multi = False              # expansion probe (hash_probe_multi)
 
     # build side is fed by the driver before probing starts
@@ -887,14 +879,15 @@ class HashJoin(Operator):
         """Accumulate one build-side batch (device-resident)."""
         self._build_batches.append(batch)
 
-    def _try_pallas_build(self, build: DeviceTable, pack) -> bool:
+    def _try_pallas_build(self, build: DeviceTable) -> bool:
         """Build the open-addressing table; False -> jnp fallback."""
         cap = int(build.validity.shape[-1])
         bound = min(self.build_rows or cap, cap)
         table_size = max(int(2 ** np.ceil(np.log2(max(2 * bound, 2)))), 2)
         if table_size > MAX_HASH_TABLE_SLOTS:
             return False
-        tk, tv = _build_hash_table(build, self.build_keys, table_size, pack)
+        tk, tv = _build_hash_table(build, self.build_keys, table_size,
+                                   self._pack)
         tk_host = np.asarray(tk)
         # every valid build row must occupy a slot: a shortfall means a key
         # collided with the empty sentinel (e.g. a -1 key) -- probing that
@@ -907,41 +900,41 @@ class HashJoin(Operator):
         return True
 
     def seal_build(self):
-        """Concatenate the build side and build the probe state (sorted
-        keys, or the open-addressing table under the pallas backend);
-        probing may start after."""
+        """Concatenate the build side, decide the key's encoding and build
+        the probe state (the open-addressing table under the pallas
+        backend, else sorted keys); probing may start after."""
         assert self._build_batches, "join build side is empty"
         build = concat_tables(self._build_batches)
         self._build_batches = []
-        kt = [build.schema[k] for k in self.build_keys]
-        self._exact = (len(kt) == 1 and kt[0].name in _PACKABLE_DTYPES)
+        keys = self.build_keys
+        ints = all(build.schema[k].name in INT_KEY_DTYPES for k in keys)
+        ranges = None
+        if ints and len(keys) > 1:
+            ranges = _read_ranges(build, keys)
+            self._pack = _pack_windows(ranges)
+        self._exact = ints and (len(keys) == 1 or self._pack is not None)
+        _count("join", "hashed_builds", int(not self._exact))
+        semi = self.join_type in ("left_semi", "left_anti")
         if kernel_ops.current_backend() == "pallas":
-            pack = None
-            key_ok = self._exact
-            if not key_ok and len(kt) >= 2:
-                # composite int-like keys: try the injective single-lane
-                # pack (a host-side range derivation — the same host sync
-                # the occupancy check below performs anyway)
-                pack = _derive_pack(build, self.build_keys)
-                key_ok = pack is not None
-            if key_ok and self._try_pallas_build(build, pack):
-                self._pack = pack
-                self._multi = not (self.join_type in ("left_semi",
-                                                      "left_anti")
-                                   or self.max_matches == 1)
+            if self._exact and self._try_pallas_build(build):
+                self._multi = not (semi or self.max_matches == 1)
                 return
-            # probe wanted a hash kernel but couldn't take it (non-integer
-            # or unpackably wide composite key, sentinel-colliding key, or
-            # a build_rows bound past the table's slot budget). Counted
-            # once per sealed build so the adaptive suite can assert warm
-            # re-plans with tighter bounds shrink it.
+            # the probe wanted a hash kernel but could not take it (hashed
+            # key, sentinel-colliding key, or a build_rows bound past the
+            # table's slot budget); counted once per sealed build
             kernel_ops.count_dispatch("fallback_probe")
-        # exact keys over a dense enough range take the direct-address
-        # index (one host read of the key range); hashed keys never do
-        self._index_slots = (_index_slots(build, self.build_keys)
-                             if self._exact else 0)
+        if self._exact:
+            lo, hi = (ranges or _read_ranges(build, keys))[0]
+            if self._pack is not None and hi >= lo:
+                lo, hi = 0, math.prod(span for _, span in self._pack) - 1
+            self._index_slots = _index_slots(lo, hi, _rows(build))
         _count("join", "index_slots", self._index_slots, max)
-        bt = _build_join_table(build, self.build_keys, self._index_slots)
+        runs = semi and not self._exact
+        bt = _build_join_table(build, keys, self._pack, self._index_slots,
+                               runs)
+        if runs:
+            bt, longest = bt
+            self._matches = _pow2(int(np.max(np.asarray(longest))))
         self._state = (build, bt)
 
     def add_input(self, batch):
@@ -963,8 +956,8 @@ class HashJoin(Operator):
         _count("join", "dense_probes" if self._index_slots
                else "sorted_probes")
         out = _probe_join(batch, self._state, self.probe_keys, self.build_keys,
-                          self.build_payload, self.join_type, self.max_matches,
-                          self._exact)
+                          self.build_payload, self.join_type, self._matches,
+                          self._pack, self._exact)
         if (self.compact and self.join_type in ("inner", "left_outer")
                 and self.max_matches > 1):
             out = compact_table(out)

@@ -45,6 +45,7 @@ from . import dtypes as dt
 from . import plan as P
 from . import tracing
 from .expr import BinaryOp, ColumnRef, Expr
+from .operators import INT_KEY_DTYPES
 
 # max_groups/max_matches are static array capacities; when the provable
 # bound exceeds this budget the optimizer leaves the hand-set hint alone
@@ -330,11 +331,12 @@ def _build_side_unique(node: P.Join, catalog) -> bool:
 
 
 def _exact_key(node: P.Join, catalog) -> bool:
-    """Mirror of HashJoin's exact-key rule: single int-like key column."""
+    """HashJoin's exact-key rule as far as a plan can see it: one int-like
+    column (whether a composite packs depends on the build's values)."""
     if len(node.build_keys) != 1:
         return False
     build = infer_schema(node.build, catalog)
-    return build[node.build_keys[0]].name in ("int32", "date32", "dict32")
+    return build[node.build_keys[0]].name in INT_KEY_DTYPES
 
 
 # ---------------------------------------------------------------------------
@@ -537,8 +539,9 @@ def derive_capacities(node: P.PlanNode, catalog,
       finite key domains), with slack, rounded up to a power of two.
     * Join ``max_matches``: 1 when a single exact key provably hits a unique
       build key; a small collision-headroom constant when the (unique) key
-      is hashed/composite; otherwise the hand-set value is kept -- the
-      optimizer never *lowers* a capacity it cannot prove.
+      is composite (packed or hashed at ``HashJoin.seal_build``, which
+      sizes a hashed semi/anti probe itself); otherwise the hand-set value
+      is kept -- the optimizer never *lowers* a capacity it cannot prove.
 
     With a feedback store, observed cardinalities tighten these further
     (only ever downward, and only under the table versions they were

@@ -67,31 +67,28 @@ def hash_combine(cols: Sequence[jax.Array]) -> jax.Array:
     return (h & jnp.uint32(0x7FFFFFFE)).astype(jnp.int32)
 
 
-def join_key(cols: Sequence[jax.Array]) -> Tuple[jax.Array, bool]:
-    """Single int32 join key. Exact for one int column, hashed otherwise.
-
-    Returns (key, exact). When not exact the caller must re-verify equality
-    of the original columns after the join (hash-bucket-then-verify, as a
-    real hash join does).
-    """
+def join_key(cols: Sequence[jax.Array]) -> jax.Array:
+    """Single int32 join key: one int column as it is, else the hash of
+    ``hash_combine``, which unequal tuples may share: a join on it reads
+    its whole hash run and verifies the original columns after (only for
+    keys ``HashJoin`` can neither keep raw nor pack with ``packed_key``)."""
     if len(cols) == 1 and jnp.issubdtype(cols[0].dtype, jnp.integer):
-        return cols[0].astype(jnp.int32), True
-    return hash_combine(cols), False
+        return cols[0].astype(jnp.int32)
+    return hash_combine(cols)
 
 
 def packed_key(cols: Sequence[jax.Array], pack: Sequence[Tuple[int, int]],
                empty_key: int = -1) -> jax.Array:
     """Injectively pack int columns into one nonnegative int32 key.
 
-    ``pack`` gives a ``(lo, span)`` window per column — the valid build
-    side's observed value range, derived host-side at ``seal_build``
-    (eligible only when the spans' product fits 2^31 - 1, so the fold
-    below never overflows int32). In-range rows map to a *unique* key in
-    ``[0, prod(spans))`` — strictly nonnegative, so a packed key can never
-    alias the empty-slot sentinel. Rows with any column outside its window
-    cannot equal any build key and map to ``empty_key`` (the probe's
-    sentinel mask then reports them unmatched); values are clipped before
-    folding so even far-out-of-range probes stay overflow-free.
+    ``pack`` gives a ``(lo, span)`` window per column: the valid build
+    side's value range, read at ``HashJoin.seal_build`` (eligible only when
+    the spans' product fits 2^31 - 1, so the fold below never overflows
+    int32). In-range rows map to a *unique* key in ``[0, prod(spans))``:
+    strictly nonnegative, so a packed key can never alias the empty-slot
+    sentinel. Rows with any column outside its window cannot equal any
+    build key and map to ``empty_key``; values are clipped before folding
+    so even far-out-of-range probes stay overflow-free.
     """
     n = cols[0].shape[0]
     key = jnp.zeros((n,), jnp.int32)
@@ -374,6 +371,14 @@ def join_probe(bt: BuildTable, probe_keys: jax.Array, probe_valid: jax.Array,
     bidx = jnp.take(bt.perm, b)
     valid = within & jnp.take(probe_valid, pi) & jnp.take(bt.validity, bidx)
     return ProbeResult(bidx, pi, valid, count)
+
+
+def longest_run(bt: BuildTable) -> jax.Array:
+    """Most valid build rows sharing one key: the candidates a probe of
+    that key reads (0 for an empty build)."""
+    k = bt.sorted_keys
+    run = jnp.arange(1, k.shape[0] + 1) - jnp.searchsorted(k, k, side="left")
+    return jnp.max(jnp.where(jnp.take(bt.validity, bt.perm), run, 0))
 
 
 def semi_mask(bt: BuildTable, probe_keys: jax.Array,

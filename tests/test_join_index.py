@@ -1,10 +1,14 @@
-"""The sorted-key join's direct-address index against its binary search.
+"""The sorted-key join's direct-address index against its binary search,
+and the join-key encoding ``HashJoin.seal_build`` decides.
 
 ``relational.join_build(..., slots=S)`` adds a prefix count of the valid
 build keys over ``[lo, lo + S)``; the probe then reads each key's
 ``[start, end)`` from it instead of two ``searchsorted`` calls. The two
 must agree for every probe key below INT32_MAX, and ``HashJoin`` must
-pick the index only for exact keys over a dense enough range.
+pick the index only for exact keys (raw or packed) over a dense enough
+range. Composite keys whose hashes collide must join exactly on both
+backends: packed where their windows fit int32, else hashed, with semi
+and anti joins reading the whole hash run.
 """
 
 from __future__ import annotations
@@ -16,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import Session
+from repro.core import Catalog, Session
 from repro.core import dtypes as dt
 from repro.core import operators as ops
 from repro.core import relational as rel
@@ -143,7 +147,7 @@ def _tables(build_keys, probe_keys, composite):
     pcols = {"k": pk, "pv": np.arange(len(pk), dtype=np.int32)}
     bschema = {"k": dt.INT32, "pay": dt.INT32}
     pschema = {"k": dt.INT32, "pv": dt.INT32}
-    if composite:                  # a hashed two-column key
+    if composite:                  # a two-column key
         bcols["g"], pcols["g"] = bk % 3, pk % 3
         bschema["g"] = pschema["g"] = dt.INT32
     b = DeviceTable.from_numpy(bcols, bschema)
@@ -173,7 +177,8 @@ def _join_rows(build, probe, join_type, max_matches, keys):
 
 # build keys: 24 keys over 0..19 with duplicates (passes the 16x rule),
 # 24 keys spread 10^6 apart (fails it), and keys up to INT32_MAX (dense,
-# but a valid INT32_MAX key keeps the binary search)
+# but a valid INT32_MAX key keeps the binary search). With g = k % 3 the
+# first packs into windows 20 x 3: S = 64 <= 16 x 32 row slots, dense
 _DENSE = [i % 20 for i in range(24)]
 _SPARSE = [i * 1_000_003 - 7_000_000 for i in range(24)]
 _TOP = [I32.max - i % 4 for i in range(24)]
@@ -186,7 +191,7 @@ _PROBE = [-3, 0, 1, 5, 5, 19, 22, 40, 2_000_003, -5_999_997, 1_000_003 * 9,
     ("left_outer", 3)])
 @pytest.mark.parametrize("build, composite, path", [
     (_DENSE, False, "dense"), (_SPARSE, False, "sorted"),
-    (_TOP, False, "sorted"), (_DENSE, True, "sorted")])
+    (_TOP, False, "sorted"), (_DENSE, True, "dense")])
 def test_hash_join_index_path(monkeypatch, join_type, max_matches, build,
                               composite, path):
     keys = ("k", "g") if composite else ("k",)
@@ -196,7 +201,9 @@ def test_hash_join_index_path(monkeypatch, join_type, max_matches, build,
     assert counts["dense_probes" if path == "dense" else "sorted_probes"] == 1
     assert counts.get("dense_probes" if path == "sorted" else
                       "sorted_probes", 0) == 0
-    assert counts["index_slots"] == (32 if path == "dense" else 0)
+    slots = 64 if composite else 32
+    assert counts["index_slots"] == (slots if path == "dense" else 0)
+    assert counts["hashed_builds"] == 0
     # the same join with the index ruled out: the binary search's rows
     monkeypatch.setattr(ops, "MAX_INDEX_SLOTS_PER_ROW", 0)
     want, counts = _join_rows(b, p, join_type, max_matches, keys)
@@ -226,3 +233,145 @@ def test_session_join_reports_index_and_warm_run_traces_nothing():
     shown = sess.sql(text).explain(analyze=True)
     stats = sess.executor_stats()["join"]
     assert "join: " + " ".join(f"{k}={v}" for k, v in stats.items()) in shown
+
+
+# ---------------------------------------------------------------------------
+# composite keys whose hashes collide join exactly on both backends
+# ---------------------------------------------------------------------------
+
+def _colliding(a, b):
+    """Indices of the tuples ``(a[i], b[i])`` whose
+    ``relational.hash_combine`` another tuple shares, ordered by hash, and
+    those hashes."""
+    h = np.asarray(rel.hash_combine([jnp.asarray(a), jnp.asarray(b)]))
+    order = np.argsort(h, kind="stable")
+    hs = h[order]
+    same = hs[1:] == hs[:-1]
+    dup = np.concatenate([[False], same]) | np.concatenate([same, [False]])
+    return order[dup], hs[dup]
+
+
+def _pair_keys(a, b):
+    return (np.asarray(a, np.int64) << 32) | (np.asarray(b, np.int64)
+                                              & 0xFFFFFFFF)
+
+
+# every tuple of [0, 2^16) x [0, 4): 2^18 build rows need 2^19 Pallas
+# slots, past MAX_HASH_TABLE_SLOTS, so both backends probe sorted keys
+_GRID_A, _GRID_B = (g.reshape(-1) for g in np.meshgrid(
+    np.arange(1 << 16, dtype=np.int32), np.arange(4, dtype=np.int32),
+    indexing="ij"))
+
+
+@pytest.mark.parametrize("join_type", ["left_semi", "left_anti"])
+@pytest.mark.parametrize("windows", ["packed", "hashed"])
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_colliding_composite_keys_join_exactly(backend, windows, join_type):
+    """Every tuple whose hash another build tuple shares is probed, so
+    each hash run's later row is asked for too. Windows 2^16 x 4 pack
+    (exact, the index); one more row at (2^30, -2^20) widens them past
+    2^31 - 1, and the key is hashed, its semi/anti probe reading the
+    build's longest hash run."""
+    a, b = _GRID_A, _GRID_B
+    if windows == "hashed":
+        a = np.append(a, np.int32(1 << 30))
+        b = np.append(b, np.int32(-(1 << 20)))
+    hit, _ = _colliding(a, b)
+    assert len(hit) >= 2
+    rng = np.random.default_rng(0)
+    pa = np.concatenate([a[hit], rng.integers(0, 1 << 16, 80)])
+    pb = np.concatenate([b[hit], rng.integers(0, 4, 64),
+                         rng.integers(4, 9, 16)])
+    build = DeviceTable.from_numpy({"a": a, "b": b},
+                                   {"a": dt.INT32, "b": dt.INT32})
+    probe = DeviceTable.from_numpy(
+        {"a": pa.astype(np.int32), "b": pb.astype(np.int32),
+         "i": np.arange(len(pa), dtype=np.int32)},
+        {"a": dt.INT32, "b": dt.INT32, "i": dt.INT32})
+    counts = {}
+    with kernel_ops.use_backend(backend), ops.collect_join_stats(counts):
+        join = ops.HashJoin(("a", "b"), ("a", "b"), join_type=join_type)
+        join.open()
+        join.add_build(build)
+        join.seal_build()
+        (out,) = join.add_input(probe)
+    got = np.asarray(out.columns["i"])[np.asarray(out.validity)]
+    member = np.isin(_pair_keys(pa, pb), _pair_keys(a, b))
+    want = np.flatnonzero(member if join_type == "left_semi" else ~member)
+    np.testing.assert_array_equal(np.sort(got), want)
+    assert counts["hashed_builds"] == (windows == "hashed")
+    assert counts.get("dense_probes", 0) == (windows == "packed")
+    if windows == "hashed":
+        assert join._matches >= 2        # the collisions' runs, read whole
+
+
+_Q5_SHAPED = ("SELECT o_nationkey, count(*) AS n, sum(o_price) AS revenue "
+              "FROM orders, customer "
+              "WHERE o_custkey = c_custkey AND o_nationkey = c_nationkey "
+              "GROUP BY o_nationkey ORDER BY o_nationkey")
+
+
+def _q5_shaped_catalog():
+    """Q5's semi join in small: 140,000 customers (past the Pallas table's
+    slots, as TPC-H SF 1's 150,000), among them pairs whose (c_custkey,
+    c_nationkey) share a hash, and orders naming each of them. Returns
+    the catalog and the numpy oracle's (nation, count, revenue)."""
+    n = 140_000
+    nation = (np.arange(1, n + 1) * 7 % 25).astype(np.int32)
+    k, g = (x.reshape(-1) for x in np.meshgrid(
+        np.arange(1, 1 << 15, dtype=np.int32),
+        np.arange(25, dtype=np.int32), indexing="ij"))
+    hit, hs = _colliding(k, g)
+    planted: list = []
+    for i in range(len(hit) - 1):
+        x, y = hit[i], hit[i + 1]
+        if (hs[i] == hs[i + 1] and k[x] != k[y]
+                and not {k[x], k[y]} & set(planted)):
+            nation[k[x] - 1], nation[k[y] - 1] = g[x], g[y]
+            planted += [k[x], k[y]]
+        if len(planted) >= 16:
+            break
+    assert len(planted) >= 2
+    rng = np.random.default_rng(5)
+    planted = np.asarray(planted, np.int32)
+    others = rng.integers(1, n + 1, 4000).astype(np.int32)
+    cust = np.concatenate([np.repeat(planted, 3), planted, others])
+    nat = nation[cust - 1]
+    wrong = np.concatenate([np.zeros(3 * len(planted), bool),
+                            np.ones(len(planted), bool),
+                            rng.random(len(others)) < 0.5])
+    nat = np.where(wrong, (nat + 1 + rng.integers(0, 24, len(cust))) % 25,
+                   nat).astype(np.int32)
+    price = rng.integers(1, 1000, len(cust)).astype(np.int32)
+    cat = Catalog()
+    cat.register_numpy("customer", {"c_custkey": np.arange(1, n + 1,
+                                                           dtype=np.int32),
+                                    "c_nationkey": nation},
+                       {"c_custkey": dt.INT32, "c_nationkey": dt.INT32},
+                       unique_keys=(("c_custkey",),))
+    cat.register_numpy("orders", {"o_custkey": cust, "o_nationkey": nat,
+                                  "o_price": price},
+                       {"o_custkey": dt.INT32, "o_nationkey": dt.INT32,
+                        "o_price": dt.INT32})
+    keep = nation[cust - 1] == nat
+    groups = np.unique(nat[keep])
+    want = [(int(q), int(np.sum(nat[keep] == q)),
+             int(price[keep][nat[keep] == q].sum())) for q in groups]
+    return cat, want
+
+
+@pytest.mark.parametrize("backend, workers", [
+    ("jnp", 1), ("pallas", 1), ("jnp", 2)])
+def test_q5_shaped_semi_join_keeps_colliding_customers(backend, workers):
+    cat, want = _q5_shaped_catalog()
+    sess = Session(cat, kernel_backend=backend, num_workers=workers,
+                   batch_rows=1 << 15,
+                   scheduler_config=SchedulerConfig(cache_results=False))
+    assert "Join(left_semi" in sess.sql(_Q5_SHAPED).explain()
+    handle = sess.sql(_Q5_SHAPED).submit()
+    res = handle.result()
+    sess.scheduler().close()
+    got = list(zip(*(np.asarray(res[c]).tolist()
+                     for c in ("o_nationkey", "n", "revenue"))))
+    assert [(int(q), int(c), int(round(r))) for q, c, r in got] == want
+    assert handle.executor_stats["join"]["hashed_builds"] == 0

@@ -309,7 +309,7 @@ def test_packed_key_property(n, ncols, span_pow):
     for _ in range(ncols):
         lo = int(rng.integers(-50, 50))
         # keep the window product inside the int32 key lane — the same
-        # eligibility bound operators._derive_pack enforces
+        # eligibility bound operators._pack_windows enforces
         budget = (np.iinfo(np.int32).max) // prod
         span = int(rng.integers(1, min(1 << span_pow, budget) + 1))
         prod *= span
